@@ -1,21 +1,7 @@
-"""Pytest bootstrap: put ``src`` on the path and keep the suite collectable
-offline.
-
-The property-test modules import ``hypothesis``; in the network-less CI
-container that package cannot be installed, so we fall back to the
-deterministic shim in :mod:`repro.testing.hypothesis_shim`.  When the real
-hypothesis is present it wins and the shim is never installed.
-"""
+"""Pytest bootstrap: put ``src`` on the path."""
 import os
 import sys
 
 _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "src")
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    from repro.testing import hypothesis_shim
-
-    hypothesis_shim.install()

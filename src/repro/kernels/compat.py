@@ -1,133 +1,18 @@
-"""Version-compat layer for the JAX/Pallas surface the kernels depend on.
+"""Interpret-mode policy shared by every Pallas kernel.
 
-JAX has renamed or moved every API this repo's accelerator code touches:
-
-* the Mosaic compiler-params class is ``pltpu.CompilerParams`` on recent
-  releases but ``pltpu.TPUCompilerParams`` on the 0.4.x line;
-* ``shard_map`` graduated from ``jax.experimental.shard_map`` to ``jax.shard_map``,
-  renaming its replication-check kwarg ``check_rep`` -> ``check_vma`` on the way;
-* Pallas kernels must run in interpret mode off-TPU, and every call site was
-  hard-coding that decision separately.
-
-This module resolves each of those **once, at import time**, so kernels and
-parallel code never touch ``jax.experimental`` names or version-sniff on
-their own.  Everything downstream imports from here:
-
-    from repro.kernels import compat
-    ...
-    compiler_params=compat.tpu_compiler_params(dimension_semantics=...)
-    compat.shard_map(f, mesh=mesh, in_specs=..., out_specs=..., check_vma=False)
-    interpret=compat.resolve_interpret(interpret)
-
-See DESIGN.md §6 for the policy discussion.
+The repo targets one installation (jax/jaxlib 0.9.0 with libtpu 0.0.34,
+``requirements-ci.txt``), so nothing here bridges API versions: kernels
+build ``pltpu.CompilerParams`` directly (a dataclass — an unknown field
+raises) and parallel code calls ``jax.shard_map`` / ``jax.lax.axis_size``.
+What is left is one decision every kernel would otherwise repeat: Pallas
+kernels run in the interpreter off-TPU (:func:`resolve_interpret`).
+See DESIGN.md §6.
 """
 from __future__ import annotations
 
-import functools
-import inspect
-from typing import Any, Callable
-
 import jax
-from jax.experimental.pallas import tpu as pltpu
 
-__all__ = [
-    "TPUCompilerParams",
-    "tpu_compiler_params",
-    "shard_map",
-    "axis_size",
-    "platform",
-    "resolve_interpret",
-]
-
-
-# ---------------------------------------------------------------------------
-# Mosaic compiler params: pltpu.CompilerParams (new) vs TPUCompilerParams (old).
-# ---------------------------------------------------------------------------
-
-TPUCompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
-_CP_FIELDS = {
-    name
-    for name in getattr(TPUCompilerParams, "__dataclass_fields__", {})
-}
-
-
-def tpu_compiler_params(**kwargs: Any):
-    """Construct the resolved compiler-params class.
-
-    Unknown fields (present only on other JAX versions) are dropped rather
-    than crashing the call site — compiler params are a performance hint, not
-    a semantic one.
-    """
-    if _CP_FIELDS:
-        kwargs = {k: v for k, v in kwargs.items() if k in _CP_FIELDS}
-    return TPUCompilerParams(**kwargs)
-
-
-# ---------------------------------------------------------------------------
-# shard_map: jax.shard_map (new) vs jax.experimental.shard_map (old), and the
-# check_vma (new) / check_rep (old) kwarg rename.
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map_impl: Callable[..., Any] = jax.shard_map
-else:  # the 0.4.x home
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SM_PARAMS = inspect.signature(_shard_map_impl).parameters
-if "check_vma" in _SM_PARAMS:
-    _CHECK_KW: str | None = "check_vma"
-elif "check_rep" in _SM_PARAMS:
-    _CHECK_KW = "check_rep"
-else:
-    _CHECK_KW = None
-
-
-def shard_map(
-    f: Callable[..., Any] | None = None,
-    *,
-    mesh: Any,
-    in_specs: Any,
-    out_specs: Any,
-    check_vma: bool | None = None,
-    check_rep: bool | None = None,
-    **kwargs: Any,
-) -> Callable[..., Any]:
-    """``shard_map`` with one spelling across JAX versions.
-
-    Accepts either ``check_vma`` (new name) or ``check_rep`` (old name) and
-    forwards whichever the installed JAX understands.  Usable directly or as
-    ``functools.partial``-style decorator factory (``f=None``).
-    """
-    if f is None:
-        return functools.partial(
-            shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, check_rep=check_rep, **kwargs)
-    check = check_vma if check_vma is not None else check_rep
-    if check is not None and _CHECK_KW is not None:
-        kwargs[_CHECK_KW] = check
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# Axis introspection inside shard_map: jax.lax.axis_size is a late addition;
-# on older releases psum of a literal 1 const-folds to the same static int.
-# ---------------------------------------------------------------------------
-
-
-def axis_size(axis_name: str):
-    """Size of a mapped mesh axis, usable inside ``shard_map`` bodies."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-# ---------------------------------------------------------------------------
-# Interpret-mode policy.
-# ---------------------------------------------------------------------------
+__all__ = ["platform", "resolve_interpret"]
 
 
 def platform() -> str:

@@ -10,12 +10,19 @@ in f32 scratch — HBM traffic drops to Q/K/V/O only.
 
 Layout and GQA
 --------------
-Operands ride in the model's native layouts — q ``(B, Sq, H, hd)``, k/v
-``(B, T, Kv, hd)`` (exactly the KV-cache layout) — and grouped-query heads
-are resolved in the *BlockSpec index map*: query head ``h`` reads KV head
-``h // (H // Kv)``, so the grouped cache is never repeated/materialized to
-the full head count (the ``jnp.repeat`` the materialized path used to pay
-every decode step).
+Callers pass the model's native layouts — q ``(B, Sq, H, hd)``, k/v
+``(B, T, Kv, hd)`` (exactly the KV-cache layout).  Every block handed to
+Mosaic has its last two dimensions either (8, 128)-aligned or whole:
+
+* prefill runs head-major — q/k/v are transposed to ``(B, H|Kv, S, hd)``
+  so a block is one head's ``(bq|bk, hd)`` tile; query head ``h`` reads
+  KV head ``h // (H // Kv)`` through the BlockSpec index map, so the
+  grouped cache is never repeated to the full head count;
+* decode reads a KV chunk as one ``(rows, Kv*hd)`` slab (a free reshape
+  of the cache / page pool) and walks the KV heads inside the body with
+  static lane slices; queries arrive grouped as ``(Kv, g, hd)`` so each
+  KV head's query group is a leading-axis index.  One grid step therefore
+  loads each KV byte once for all query heads.
 
 Runtime ``kv_len``
 ------------------
@@ -28,10 +35,12 @@ Tiling
 ------
 ``flash_attention_pallas``: grid ``(B, H, ceil(Sq/bq), ceil(T/bk))`` with the
 KV axis innermost/sequential ("arbitrary") so the scratch carry is valid.
-``flash_decode_pallas``: grid ``(B, H, ceil(T/bk))`` with the KV-chunk axis
-*parallel* — each chunk emits (o, m, l) online-softmax partials and a tiny
-merge pass (plain jnp, see ``numerics/attention.py``) log-sum-exp-combines
-them; this is the TPU form of flash-decoding's split-KV scheme.
+``flash_decode_pallas``: grid ``(B, ceil(T/bk))`` with the KV-chunk axis
+*parallel* — each chunk emits (o, m, l) online-softmax partials for every
+head and a tiny merge pass (plain jnp, see ``numerics/attention.py``)
+log-sum-exp-combines them; this is the TPU form of flash-decoding's
+split-KV scheme.  Partials are laid out ``(B, n_chunks, H, hd|1)`` so the
+lane dimension of every output block is ``hd`` or whole.
 
 Blocks need not divide the sequence dims: out-of-bounds tiles are padded by
 the runtime (NaN in interpret mode, clamped reads under Mosaic), so every
@@ -39,7 +48,11 @@ tile is sanitized against its true extent before it enters the accumulation.
 
 Exactness: this is *exact* attention (same math as the reference, different
 summation order); tests sweep GQA ratios / causal / ragged ``kv_len``
-against ``ref.py``.
+against ``ref.py``.  The probabilities are never rounded to the cache
+dtype: P·V runs in f32, as in the materialized path
+(``models/attention._core``), so the two differ by summation order only —
+rounding the unnormalized online-softmax P to bf16 while the materialized
+path rounds the normalized one made them disagree by ~6e-4 relative.
 
 Mesh contract
 -------------
@@ -75,9 +88,9 @@ def _attn_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, acc, m, lsum, *,
                  sq: int):
     """One (b, h, qi, ki) grid step.
 
-    kvlen_ref: (B,) int32 in SMEM;  q_ref: (1, bq, 1, hd);
-    k_ref/v_ref: (1, bk, 1, hd) — the KV head was selected by the BlockSpec
-    index map;  o_ref: (1, bq, 1, hd).
+    kvlen_ref: (B,) int32 in SMEM;  q_ref: (1, 1, bq, hd);
+    k_ref/v_ref: (1, 1, bk, hd) — the KV head was selected by the BlockSpec
+    index map;  o_ref: (1, 1, bq, hd).
     acc: (bq, hd) f32 scratch;  m, lsum: (bq, 1) f32 scratch.
     """
     b = pl.program_id(0)
@@ -95,9 +108,9 @@ def _attn_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, acc, m, lsum, *,
     k_rows = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
     # sanitize padded tails: OOB tiles hold NaN (interpret) or clamped reads
     # (Mosaic); zeroed rows keep the matmuls finite and are masked below
-    qb = jnp.where(q_rows < sq, q_ref[0, :, 0, :], 0.0)
-    kb = jnp.where(k_rows < kv_len, k_ref[0, :, 0, :], 0.0)
-    vb = jnp.where(k_rows < kv_len, v_ref[0, :, 0, :], 0.0)
+    qb = jnp.where(q_rows < sq, q_ref[0, 0], 0.0)
+    kb = jnp.where(k_rows < kv_len, k_ref[0, 0], 0.0)
+    vb = jnp.where(k_rows < kv_len, v_ref[0, 0], 0.0)
 
     s = jax.lax.dot_general(
         qb, kb, (((1,), (1,)), ((), ())),
@@ -115,14 +128,14 @@ def _attn_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, acc, m, lsum, *,
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)         # (bq, bk)
     lsum[...] = lsum[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     pv = jax.lax.dot_general(
-        p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+        p, vb.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)              # (bq, hd)
     acc[...] = acc[...] * alpha + pv
     m[...] = m_new
 
     @pl.when(ki == n_k - 1)
     def _final():
-        o_ref[0, :, 0, :] = (acc[...] / jnp.maximum(lsum[...], 1e-30)).astype(
+        o_ref[0, 0] = (acc[...] / jnp.maximum(lsum[...], 1e-30)).astype(
             o_ref.dtype)
 
 
@@ -162,66 +175,108 @@ def flash_attention_pallas(
     n_k = -(-T // bk)
 
     grid = (B, H, n_q, n_k)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_attn_kernel, n_k=n_k, causal=causal,
                           scale=1.0 / (hd ** 0.5), bq=bq, bk=bk, sq=Sq),
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, 1, hd), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, h, i, j: (b, j, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, h, i, j: (b, j, h // g, 0)),
+            pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bk, hd),
+                         lambda b, h, i, j: (b, h // g, j, 0)),
+            pl.BlockSpec((1, 1, bk, hd),
+                         lambda b, h, i, j: (b, h // g, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, hd),
+                               lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, hd), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(kv_len, q, k, v)
+    )(kv_len, jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+      jnp.swapaxes(v, 1, 2))
+    return jnp.swapaxes(out, 1, 2)
+
+
+def _group_partial(qg, kb, vb, valid, scale):
+    """Online-softmax partial of one KV head's query group over one chunk.
+
+    qg: (g, hd) queries;  kb, vb: (rows, hd) sanitized KV rows;  valid:
+    (1, rows) bool, lane-major (Mosaic cannot transpose a mask).  Returns
+    ``(o (g, hd) f32, m (g, 1), l (g, 1))``.  An all-masked chunk gives
+    m = -inf-ish and p = 0 everywhere -> l = 0, o = 0; the merge pass
+    weighs it out (its exp(m_c - m_max) underflows).
+    """
+    dt = jnp.promote_types(qg.dtype, kb.dtype)
+    s = jax.lax.dot_general(
+        qg.astype(dt), kb.astype(dt), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale      # (g, rows)
+    s = jnp.where(valid, s, _NEG_INF)
+    m_c = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(valid, jnp.exp(s - m_c), 0.0)
+    l_c = jnp.sum(p, axis=-1, keepdims=True)
+    o_c = jax.lax.dot_general(
+        p, vb.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)              # (g, hd)
+    return o_c, m_c, l_c
+
+
+def _chunk_rows(chunk, rows: int, axis: int) -> jax.Array:
+    """Logical KV row ids of a chunk as a column (axis=0) or row (axis=1)."""
+    shape = (rows, 1) if axis == 0 else (1, rows)
+    return chunk * rows + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
 
 
 def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
-                   bk: int, scale: float):
-    """One (b, h, ki) grid step of the split-KV decode schedule.
+                   bk: int, scale: float, n_kv: int, hd: int):
+    """One (b, ki) grid step of the split-KV decode schedule.
 
     Each KV chunk is independent (*parallel* grid axis — no scratch carry):
-    it emits its own online-softmax partial (o, m, l) and the merge pass
-    combines them.  kvlen_ref: (B,) int32 in SMEM;  q_ref: (1, 1, hd);
-    k_ref/v_ref: (1, bk, 1, hd);  o_ref: (1, 1, hd, 1);  m_ref/l_ref:
-    (1, 1, 1).
+    it emits its own online-softmax partial (o, m, l) for every head and the
+    merge pass combines them.  kvlen_ref: (B,) int32 in SMEM;  q_ref:
+    (1, Kv, g, hd);  k_ref/v_ref: (1, bk, Kv*hd);  o_ref: (1, 1, Kv, g, hd);
+    m_ref/l_ref: (1, 1, Kv, g, 1).
     """
     b = pl.program_id(0)
-    ki = pl.program_id(2)
+    ki = pl.program_id(1)
     kv_len = kvlen_ref[b]
-    k_rows = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-    valid = k_rows < kv_len
-    kb = jnp.where(valid, k_ref[0, :, 0, :], 0.0)
-    vb = jnp.where(valid, v_ref[0, :, 0, :], 0.0)
-    qb = q_ref[0]                                        # (1, hd)
-    s = jax.lax.dot_general(
-        qb, kb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale      # (1, bk)
-    s = jnp.where(valid.T, s, _NEG_INF)
-    m_c = jnp.max(s, axis=-1, keepdims=True)             # (1, 1)
-    # all-masked chunk: m_c = -inf and p = 0 everywhere -> l = 0, o = 0;
-    # the merge pass weighs it out (its exp(m_c - m_max) underflows to 0)
-    p = jnp.where(valid.T, jnp.exp(s - m_c), 0.0)
-    l_c = jnp.sum(p, axis=-1, keepdims=True)
-    o_c = jax.lax.dot_general(
-        p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (1, hd)
-    o_ref[0, 0, :, 0] = o_c[0]
-    m_ref[0, 0, 0] = m_c[0, 0]
-    l_ref[0, 0, 0] = l_c[0, 0]
+    valid = _chunk_rows(ki, bk, 0) < kv_len              # (bk, 1)
+    valid_t = _chunk_rows(ki, bk, 1) < kv_len            # (1, bk)
+    for h in range(n_kv):
+        lanes = slice(h * hd, (h + 1) * hd)
+        kb = jnp.where(valid, k_ref[0, :, lanes], 0.0)
+        vb = jnp.where(valid, v_ref[0, :, lanes], 0.0)
+        o_c, m_c, l_c = _group_partial(q_ref[0, h], kb, vb, valid_t, scale)
+        o_ref[0, 0, h] = o_c
+        m_ref[0, 0, h] = m_c
+        l_ref[0, 0, h] = l_c
+
+
+def _split_partials(o, m, l, H):
+    """(B, n, Kv, g, hd|1) kernel outputs -> merge layout (B, n, H, ...)."""
+    B, n = o.shape[:2]
+    return (o.reshape(B, n, H, o.shape[-1]), m.reshape(B, n, H),
+            l.reshape(B, n, H))
+
+
+def _partial_specs(H: int, n_kv: int, hd: int, index_map):
+    g = H // n_kv
+    return [pl.BlockSpec((1, 1, n_kv, g, hd), index_map),
+            pl.BlockSpec((1, 1, n_kv, g, 1), index_map),
+            pl.BlockSpec((1, 1, n_kv, g, 1), index_map)]
+
+
+def _partial_shapes(B: int, n: int, H: int, n_kv: int, hd: int):
+    g = H // n_kv
+    return [jax.ShapeDtypeStruct((B, n, n_kv, g, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, n, n_kv, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, n, n_kv, g, 1), jnp.float32)]
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
@@ -241,8 +296,8 @@ def flash_decode_pallas(
       k, v: (B, T, Kv, hd) — the KV cache, heads ungrouped;
       kv_len: (B,) int32 runtime valid-prefix length (<= T).
     Returns:
-      ``(o_part (B, H, hd, n_chunks) f32, m_part (B, H, n_chunks) f32,
-      l_part (B, H, n_chunks) f32)`` — merge with
+      ``(o_part (B, n_chunks, H, hd) f32, m_part (B, n_chunks, H) f32,
+      l_part (B, n_chunks, H) f32)`` — merge with
       :func:`repro.numerics.attention.merge_decode_partials`.
     """
     interpret = compat.resolve_interpret(interpret)
@@ -253,30 +308,27 @@ def flash_decode_pallas(
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (B,))
     n_k = -(-T // bk)
 
-    grid = (B, H, n_k)
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, bk=bk, scale=1.0 / (hd ** 0.5)),
-        grid=grid,
+    def out_map(b, j):
+        return (b, j, 0, 0, 0)
+
+    o, m, l = pl.pallas_call(
+        functools.partial(_decode_kernel, bk=bk, scale=1.0 / (hd ** 0.5),
+                          n_kv=Kv, hd=hd),
+        grid=(B, n_k),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, hd), lambda b, h, j: (b, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, j: (b, j, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, j: (b, j, h // g, 0)),
+            pl.BlockSpec((1, Kv, g, hd), lambda b, j: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bk, Kv * hd), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, Kv * hd), lambda b, j: (b, j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, hd, 1), lambda b, h, j: (b, h, 0, j)),
-            pl.BlockSpec((1, 1, 1), lambda b, h, j: (b, h, j)),
-            pl.BlockSpec((1, 1, 1), lambda b, h, j: (b, h, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, hd, n_k), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, n_k), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, n_k), jnp.float32),
-        ],
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel")),
+        out_specs=_partial_specs(H, Kv, hd, out_map),
+        out_shape=_partial_shapes(B, n_k, H, Kv, hd),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(kv_len, q, k, v)
+    )(kv_len, q.reshape(B, Kv, g, hd), k.reshape(B, T, Kv * hd),
+      v.reshape(B, T, Kv * hd))
+    return _split_partials(o, m, l, H)
 
 
 def _unpack_crt(byte: jax.Array, moduli: tuple[int, int]) -> jax.Array:
@@ -309,75 +361,78 @@ def _unpack_crt(byte: jax.Array, moduli: tuple[int, int]) -> jax.Array:
     return r1 + m1 * t
 
 
-def _paged_decode_kernel(tab_ref, kvlen_ref, q_ref, *rest, ps: int,
-                         scale: float, moduli: tuple[int, int] | None,
-                         red_moduli: tuple[int, ...] | None, g: int):
-    """One (b, h, j) grid step: page ``tab[b, j]`` of the split-KV schedule.
+def _paged_decode_kernel(tab_ref, kvlen_ref, q_ref, k_ref, v_ref, *rest,
+                         ps: int, scale: float, n_kv: int, hd: int,
+                         moduli: tuple[int, int] | None,
+                         red_moduli: tuple[int, ...] | None):
+    """One (b, j) grid step: page ``tab[b, j]`` of the split-KV schedule.
 
     The scalar-prefetched block table already steered the BlockSpec index
     maps at page ``tab[b, j]``, so the kernel body only sees this request's
-    j-th page; masking is against the *logical* row ``j*ps + slot`` exactly
-    like the dense chunk kernel.  With ``moduli`` set, k/v arrive as packed
-    uint8 residue planes plus an f32 per-(slot, head... ) scale block and are
+    j-th page — one ``(ps, lanes)`` slab holding every KV head; masking is
+    against the *logical* row ``j*ps + slot`` exactly like the dense chunk
+    kernel.  With ``moduli`` set, k/v arrive as packed uint8 residue planes
+    (lane group 0 = the packed info bytes of all heads, groups 1..r the
+    witness residues) plus an f32 per-(slot, head) scale slab and are
     dequantized in-register before the dot products.
 
-    With ``red_moduli`` the page's witness lanes ride along as extra
-    operands and the kernel emits a fourth reduction output: the count of
-    valid (row, hd) elements on this page whose stored witness residues
-    disagree with the packed info byte it just decoded — KV integrity is
-    checked *while the planes are in VMEM*, for free on the decode hot
-    path.  Only the lead query head of each GQA group (``h % g == 0``)
-    reports its KV head's count, so summing the output over heads and
-    pages counts every faulty element exactly once.
+    With ``red_moduli`` the kernel emits a fourth reduction output: the
+    count of valid (row, head, hd) elements on this page whose stored
+    witness residues disagree with the packed info byte it just decoded —
+    KV integrity is checked *while the planes are in VMEM*, for free on the
+    decode hot path.  Each page step counts each of its elements once.
     """
     if moduli is None:
-        k_ref, v_ref, o_ref, m_ref, l_ref = rest
+        o_ref, m_ref, l_ref = rest
     elif red_moduli is None:
-        k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref = rest
+        ks_ref, vs_ref, o_ref, m_ref, l_ref = rest
     else:
-        (k_ref, v_ref, ks_ref, vs_ref, kw_ref, vw_ref,
-         o_ref, m_ref, l_ref, syn_ref) = rest
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, syn_ref = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     kv_len = kvlen_ref[b]
-    k_rows = j * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-    valid = k_rows < kv_len
-    if moduli is None:
-        kb = k_ref[0, :, 0, :]
-        vb = v_ref[0, :, 0, :]
-    else:
-        k_int = _unpack_crt(k_ref[0, :, 0, :].astype(jnp.int32), moduli)
-        v_int = _unpack_crt(v_ref[0, :, 0, :].astype(jnp.int32), moduli)
-        if red_moduli is not None:
-            def bad(x_int, w_ref):
-                mism = jnp.zeros(x_int.shape, jnp.bool_)
-                for jw, m in enumerate(red_moduli):
-                    wit = w_ref[0, :, jw, 0, :].astype(jnp.int32)
-                    mism = mism | (jnp.remainder(
-                        wit - jnp.remainder(x_int, m), m) != 0)
-                return mism & valid
-            cnt = (jnp.sum(bad(k_int, kw_ref).astype(jnp.int32))
-                   + jnp.sum(bad(v_int, vw_ref).astype(jnp.int32)))
-            lead = pl.program_id(1) % g == 0
-            syn_ref[0, 0, 0] = jnp.where(lead, cnt, 0)
-        kb = k_int.astype(jnp.float32) * ks_ref[0, :, 0, :]  # (ps, 1) scale
-        vb = v_int.astype(jnp.float32) * vs_ref[0, :, 0, :]
-    kb = jnp.where(valid, kb, 0.0)
-    vb = jnp.where(valid, vb, 0.0)
-    qb = q_ref[0]                                        # (1, hd)
-    s = jax.lax.dot_general(
-        qb, kb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale      # (1, ps)
-    s = jnp.where(valid.T, s, _NEG_INF)
-    m_c = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.where(valid.T, jnp.exp(s - m_c), 0.0)
-    l_c = jnp.sum(p, axis=-1, keepdims=True)
-    o_c = jax.lax.dot_general(
-        p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (1, hd)
-    o_ref[0, 0, :, 0] = o_c[0].astype(jnp.float32)
-    m_ref[0, 0, 0] = m_c[0, 0]
-    l_ref[0, 0, 0] = l_c[0, 0]
+    valid = _chunk_rows(j, ps, 0) < kv_len               # (ps, 1)
+    valid_t = _chunk_rows(j, ps, 1) < kv_len             # (1, ps)
+    hdp = hd if moduli is None else hd // PackedFormat.for_moduli(
+        moduli).values_per_byte
+    group = n_kv * hdp                        # lanes of one plane lane group
+
+    def lanes(lane_group, h):
+        lo = lane_group * group + h * hdp
+        return slice(lo, lo + hdp)
+
+    def bad(x_int, w_ref, h):
+        mism = jnp.zeros(x_int.shape, jnp.bool_)
+        for jw, m in enumerate(red_moduli):
+            wit = w_ref[0, :, lanes(1 + jw, h)].astype(jnp.int32)
+            mism = mism | (jnp.remainder(
+                wit - jnp.remainder(x_int, m), m) != 0)
+        cnt = jnp.sum((mism & valid).astype(jnp.int32), axis=1,
+                      keepdims=True)
+        return jnp.sum(cnt, axis=0, keepdims=True)       # (1, 1)
+
+    syn = jnp.zeros((1, 1), jnp.int32)
+    for h in range(n_kv):
+        if moduli is None:
+            kb = k_ref[0, :, lanes(0, h)]
+            vb = v_ref[0, :, lanes(0, h)]
+        else:
+            k_int = _unpack_crt(k_ref[0, :, lanes(0, h)].astype(jnp.int32),
+                                moduli)
+            v_int = _unpack_crt(v_ref[0, :, lanes(0, h)].astype(jnp.int32),
+                                moduli)
+            if red_moduli is not None:
+                syn = syn + bad(k_int, k_ref, h) + bad(v_int, v_ref, h)
+            kb = k_int.astype(jnp.float32) * ks_ref[0, :, h:h + 1]
+            vb = v_int.astype(jnp.float32) * vs_ref[0, :, h:h + 1]
+        kb = jnp.where(valid, kb, 0.0)
+        vb = jnp.where(valid, vb, 0.0)
+        o_c, m_c, l_c = _group_partial(q_ref[0, h], kb, vb, valid_t, scale)
+        o_ref[0, 0, h] = o_c
+        m_ref[0, 0, h] = m_c
+        l_ref[0, 0, h] = l_c
+    if red_moduli is not None:
+        syn_ref[0, 0] = syn
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "moduli",
@@ -393,8 +448,6 @@ def flash_paged_decode_pallas(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     moduli: tuple[int, int] | None = None,
-    k_witness: jax.Array | None = None,
-    v_witness: jax.Array | None = None,
     red_moduli: tuple[int, ...] | None = None,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, ...]:
@@ -409,87 +462,81 @@ def flash_paged_decode_pallas(
     Args:
       q: (B, H, hd) decode-token queries.
       k_pages, v_pages: (P, ps, Kv, hd) pool (cache dtype), or with
-        ``moduli`` set the packed planes (P, ps, Kv, hd/vpb) uint8 plus
-        ``k_scale``/``v_scale`` (P, ps, Kv, 1) f32.
+        ``moduli`` set the residue planes (P, ps, 1 + r, Kv, hd/vpb) uint8
+        (lane 0 the packed info byte, lanes 1..r redundant witnesses) plus
+        ``k_scale``/``v_scale`` (P, ps, Kv, 1) f32.  Pools are passed whole
+        — the kernel picks its lanes, so no per-step slice is copied.
       block_tab: (B, n_pmax) int32 page ids per request; entries past the
         live prefix may point anywhere (masked by ``kv_len``).
       kv_len: (B,) int32 valid-prefix length (<= n_pmax * page_size).
-      k_witness, v_witness: with ``red_moduli`` set, the redundant witness
-        lanes (P, ps, r, Kv, hd) uint8 of the same pool — the kernel then
-        also accumulates a per-(b, h, j) syndrome count.
+      red_moduli: the witness moduli — the kernel then also accumulates a
+        per-(b, page) syndrome count from the witness lanes.
     Returns:
-      ``(o (B, H, hd, n_pmax), m (B, H, n_pmax), l (B, H, n_pmax))`` f32
+      ``(o (B, n_pmax, H, hd), m (B, n_pmax, H), l (B, n_pmax, H))`` f32
       partials for :func:`repro.numerics.attention.merge_decode_partials`;
-      with ``red_moduli`` a fourth ``syn (B, H, n_pmax)`` int32 element
-      counting witness mismatches on valid rows (nonzero only on GQA lead
-      heads, so ``syn.sum((1, 2))`` is the per-request faulty-element count).
+      with ``red_moduli`` a fourth ``syn (B,)`` int32 counting witness
+      mismatches on the request's valid rows.
     """
     interpret = compat.resolve_interpret(interpret)
     B, H, hd = q.shape
-    _, ps, Kv, _ = k_pages.shape
+    P, ps = k_pages.shape[:2]
     assert ps == page_size, (ps, page_size)
+    Kv = k_pages.shape[-2]
     assert H % Kv == 0, (H, Kv)
     g = H // Kv
     block_tab = jnp.asarray(block_tab, jnp.int32)
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (B,))
     n_pmax = block_tab.shape[1]
-    hd_store = k_pages.shape[-1]
-
-    # all index maps receive the scalar-prefetch refs after the grid coords
-    in_specs = [
-        pl.BlockSpec((1, 1, hd), lambda b, h, j, tab, kvl: (b, h, 0)),
-        pl.BlockSpec((1, ps, 1, hd_store),
-                     lambda b, h, j, tab, kvl: (tab[b, j], 0, h // g, 0)),
-        pl.BlockSpec((1, ps, 1, hd_store),
-                     lambda b, h, j, tab, kvl: (tab[b, j], 0, h // g, 0)),
-    ]
-    operands = [q, k_pages, v_pages]
+    # one page = one (ps, lanes) slab: a free reshape of the pool
+    row = k_pages[0, 0].size
     if moduli is not None:
         assert k_scale is not None and v_scale is not None
-        for _ in range(2):
-            in_specs.append(pl.BlockSpec(
-                (1, ps, 1, 1),
-                lambda b, h, j, tab, kvl: (tab[b, j], 0, h // g, 0)))
-        operands += [k_scale, v_scale]
-    if red_moduli is not None:
-        assert moduli is not None
-        assert k_witness is not None and v_witness is not None
-        r = len(red_moduli)
-        assert k_witness.shape[2] == r, (k_witness.shape, red_moduli)
-        for _ in range(2):
-            in_specs.append(pl.BlockSpec(
-                (1, ps, r, 1, hd_store),
-                lambda b, h, j, tab, kvl: (tab[b, j], 0, 0, h // g, 0)))
-        operands += [k_witness, v_witness]
+        assert red_moduli is None or k_pages.shape[2] == 1 + len(
+            red_moduli), (k_pages.shape, red_moduli)
+    else:
+        assert red_moduli is None
 
-    out_specs = [
-        pl.BlockSpec((1, 1, hd, 1), lambda b, h, j, tab, kvl: (b, h, 0, j)),
-        pl.BlockSpec((1, 1, 1), lambda b, h, j, tab, kvl: (b, h, j)),
-        pl.BlockSpec((1, 1, 1), lambda b, h, j, tab, kvl: (b, h, j)),
+    def page_map(b, j, tab, kvl):
+        return (tab[b, j], 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, Kv, g, hd), lambda b, j, tab, kvl: (b, 0, 0, 0)),
+        pl.BlockSpec((1, ps, row), page_map),
+        pl.BlockSpec((1, ps, row), page_map),
     ]
-    out_shape = [
-        jax.ShapeDtypeStruct((B, H, hd, n_pmax), jnp.float32),
-        jax.ShapeDtypeStruct((B, H, n_pmax), jnp.float32),
-        jax.ShapeDtypeStruct((B, H, n_pmax), jnp.float32),
-    ]
+    operands = [q.reshape(B, Kv, g, hd), k_pages.reshape(P, ps, row),
+                v_pages.reshape(P, ps, row)]
+    if moduli is not None:
+        in_specs += [pl.BlockSpec((1, ps, Kv), page_map)] * 2
+        operands += [k_scale.reshape(P, ps, Kv), v_scale.reshape(P, ps, Kv)]
+
+    def out_map(b, j, tab, kvl):
+        return (b, j, 0, 0, 0)
+
+    out_specs = _partial_specs(H, Kv, hd, out_map)
+    out_shape = _partial_shapes(B, n_pmax, H, Kv, hd)
     if red_moduli is not None:
-        out_specs.append(
-            pl.BlockSpec((1, 1, 1), lambda b, h, j, tab, kvl: (b, h, j)))
-        out_shape.append(jax.ShapeDtypeStruct((B, H, n_pmax), jnp.int32))
+        out_specs.append(pl.BlockSpec(
+            (1, 1, 1, 1), lambda b, j, tab, kvl: (b, j, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((B, n_pmax, 1, 1), jnp.int32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, n_pmax),
+        grid=(B, n_pmax),
         in_specs=in_specs,
         out_specs=out_specs,
     )
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(_paged_decode_kernel, ps=ps,
-                          scale=1.0 / (hd ** 0.5), moduli=moduli,
-                          red_moduli=red_moduli, g=g),
+                          scale=1.0 / (hd ** 0.5), n_kv=Kv, hd=hd,
+                          moduli=moduli, red_moduli=red_moduli),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(block_tab, kv_len, *operands)
+    parts = _split_partials(*outs[:3], H)
+    if red_moduli is None:
+        return parts
+    return (*parts, outs[3].sum(axis=(1, 2, 3)))

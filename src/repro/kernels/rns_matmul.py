@@ -23,6 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
 
@@ -34,11 +35,12 @@ DEFAULT_BLOCKS = (128, 128, 512)  # (bm, bn, bk)
 def _kernel(m_ref, a_ref, b_ref, out_ref, *, n_k: int):
     """One (channel, i, j, k) grid step.
 
-    m_ref:  (1,)        int32   channel modulus (SMEM-ish scalar)
+    m_ref:  (C,)        int32   channel moduli, whole array in SMEM
     a_ref:  (1, bm, bk) int8    centered residues of A
     b_ref:  (1, bk, bn) int8    centered residues of B
     out_ref:(1, bm, bn) int32   accumulator / final centered residues
     """
+    m = m_ref[pl.program_id(0)]
     k = pl.program_id(3)
 
     a = a_ref[0]
@@ -59,7 +61,6 @@ def _kernel(m_ref, a_ref, b_ref, out_ref, *, n_k: int):
     # Single deferred reduction: centered remainder on the last K step.
     @pl.when(k == n_k - 1)
     def _reduce():
-        m = m_ref[0]
         acc = out_ref[0]
         r = jax.lax.rem(acc, m)           # sign of dividend; |r| < m
         r = jnp.where(r < 0, r + m, r)    # canonical [0, m)
@@ -104,13 +105,13 @@ def rns_matmul_pallas(
         functools.partial(_kernel, n_k=n_k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda c, i, j, k: (c,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bm, bk), lambda c, i, j, k: (c, i, k)),
             pl.BlockSpec((1, bk, bn), lambda c, i, j, k: (c, k, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda c, i, j, k: (c, i, j)),
         out_shape=jax.ShapeDtypeStruct((C, M, N), jnp.int32),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")
         ),

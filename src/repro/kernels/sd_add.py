@@ -15,6 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
 
@@ -99,7 +100,7 @@ def sd_add_pallas(
         ],
         out_specs=pl.BlockSpec((bb, nd), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, nd), jnp.int8),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, y)

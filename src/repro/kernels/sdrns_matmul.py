@@ -20,6 +20,15 @@ Tiling: grid ``(C, M/bm, N/bn)`` — channel and both matmul dims parallel; the
 K and digit axes ride whole inside the body (digit tensors are small: the
 paper's channels are n <= 21 digits, and K is pre-segmented by ops.py).
 
+Mosaic status: the body is not TPU-legal yet — it builds 5-D digit tensors
+with the digit axis (n = 7 under P21) as the lane dimension and reduces them
+with strided pairwise slices, which Mosaic refuses ("Only 2D gather is
+supported"), and its ``(n, bm, K, bn, n)`` partial-product stack is sized
+in logical rather than tiled bytes.  Both entry points therefore run only
+in the Pallas interpreter and raise :class:`NotImplementedError` when asked
+for Mosaic (``interpret=False``, or ``None`` on a TPU) — ``system="sdrns"``
+never silently degrades to another implementation on the chip.
+
 Bit-exactness: the reduction structure (pairwise 0::2/1::2 trees with zero
 padding on odd counts) mirrors :func:`repro.core.sdrns.modular_mul` exactly,
 so the output *digit vectors* — not just the decoded values — match the
@@ -32,6 +41,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import sd
 from repro.core.sdrns import WRAP_SIGNS
@@ -79,15 +89,24 @@ def _tree_reduce(pp: jax.Array, axis: int, ws: jax.Array) -> jax.Array:
         pp, axis, lambda x, y: _modular_add(x, y, ws))
 
 
+def _require_interpret(interpret: bool | None) -> None:
+    if not compat.resolve_interpret(interpret):
+        raise NotImplementedError(
+            "the fused SD-RNS kernel is not Mosaic-legal (5-D digit tensors "
+            "with the digit axis as lanes, strided adder-tree slices); run "
+            "system='sdrns' with backend='interpret' or 'ref', or serve "
+            "system='rns' on TPU")
+
+
 def _kernel(ws_ref, a_ref, b_ref, out_ref, *, n: int):
     """One (channel, i, j) grid step — a full SD-RNS tile product.
 
-    ws_ref:  (1,)            int32  channel wrap sign (+1/0/-1)
+    ws_ref:  (C,)            int32  wrap signs (+1/0/-1), whole array in SMEM
     a_ref:   (1, bm, K, n)   int8   SD digits of A's residues
     b_ref:   (1, K, bn, n)   int8   SD digits of B's residues
     out_ref: (1, bm, bn, n)  int8   SD digits of (A @ B) mod m_c
     """
-    ws = ws_ref[0].astype(jnp.int8)
+    ws = ws_ref[pl.program_id(0)].astype(jnp.int8)
     a = a_ref[0]                                     # (bm, K, n)
     b = b_ref[0]                                     # (K, bn, n)
 
@@ -125,9 +144,10 @@ def sdrns_matmul_pallas(
       (C, M, N, n) int8 SD digits of (A @ B) mod m_c per channel.
 
     M % bm == 0 and N % bn == 0 (ops.py pads).  ``interpret=None``
-    auto-selects the Pallas interpreter off-TPU.
+    auto-selects the Pallas interpreter off-TPU; Mosaic is refused (see
+    the module docstring).
     """
-    interpret = compat.resolve_interpret(interpret)
+    _require_interpret(interpret)
     C, M, K, n = a_dig.shape
     _, K2, N, n2 = b_dig.shape
     assert (K, n) == (K2, n2), (a_dig.shape, b_dig.shape)
@@ -138,15 +158,15 @@ def sdrns_matmul_pallas(
         functools.partial(_kernel, n=n),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda c, i, j: (c,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bm, K, n), lambda c, i, j: (c, i, 0, 0)),
             pl.BlockSpec((1, K, bn, n), lambda c, i, j: (c, 0, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn, n), lambda c, i, j: (c, i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((C, M, N, n), jnp.int8),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
-        interpret=interpret,
+        interpret=True,
     )(wrap_signs.astype(jnp.int32), a_dig, b_dig)
 
 
@@ -178,8 +198,9 @@ def sdrns_matvec_pallas(
       wrap_signs: (C,) int32 end-around signs per channel.
     Returns:
       (C, M, N, n) int8 SD digits of (A @ B) mod m_c per channel.
+    Interpreter only, like :func:`sdrns_matmul_pallas`.
     """
-    interpret = compat.resolve_interpret(interpret)
+    _require_interpret(interpret)
     C, M, K, n = a_dig.shape
     _, K2, N, n2 = b_dig.shape
     assert (K, n) == (K2, n2), (a_dig.shape, b_dig.shape)
@@ -190,13 +211,13 @@ def sdrns_matvec_pallas(
         functools.partial(_kernel, n=n),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda c, j: (c,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, M, K, n), lambda c, j: (c, 0, 0, 0)),
             pl.BlockSpec((1, K, bn, n), lambda c, j: (c, 0, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, M, bn, n), lambda c, j: (c, 0, j, 0)),
         out_shape=jax.ShapeDtypeStruct((C, M, N, n), jnp.int8),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=True,
     )(wrap_signs.astype(jnp.int32), a_dig, b_dig)

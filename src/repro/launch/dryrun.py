@@ -1,12 +1,20 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x shape x mesh)
 cell with ShapeDtypeStruct inputs (no allocation) on placeholder devices.
 
-The two lines above MUST stay the first statements in this module — jax locks
-the device count at first init, and smoke tests / benches must keep seeing
-one device, so the flag lives here and only here.
+These are CPU placeholder compiles: 512 forced *host* devices stand in for
+the pod, so the programs are compiled by XLA's CPU backend, not by the TPU
+compiler — they check sharding rules, collectives and memory accounting,
+not Mosaic legality (``tests/test_tpu_compile.py`` does that for the
+kernels).  ``JAX_PLATFORMS=cpu`` is pinned so the ``--all`` children never
+take a machine's TPU either.
+
+The three lines above MUST stay the first statements in this module — jax
+locks the device count at first init, and smoke tests / benches must keep
+seeing one device, so the flags live here and only here.
 
 Per cell this driver:
   1. builds the model + step function (train_step for train_4k,

@@ -2,7 +2,8 @@
 
 CPU demo runs the reduced config; the full configs lower through the same
 prefill/decode step functions in launch/dryrun.py (decode_32k / long_500k
-cells).
+cells).  :func:`build_engine` is the one place a served model is
+assembled; ``chip_smoke.py`` serves through the engine it returns.
 
 Example:
   PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --reduced \
@@ -11,23 +12,28 @@ Example:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.serving.engine import ServingEngine
 
 
-def main(argv=None):
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--system", "--backend", dest="system", default="bns",
                     choices=("bns", "rns", "sdrns"),
                     help="number system (--backend is a deprecated alias)")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to this many layers (widths stay "
+                         "published)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=16)
@@ -37,30 +43,51 @@ def main(argv=None):
                     help="keep weights float and convert per call (baseline "
                          "for the residue-resident default; see "
                          "benchmarks/serving_bench.py)")
+    ap.add_argument("--kv-format", default="bf16",
+                    choices=("bf16", "rns8", "rns4", "rns8r"),
+                    help="KV page storage of the paged engine")
+    ap.add_argument("--policy", default="off",
+                    choices=("off", "detect", "correct", "strict"),
+                    help="KV fault policy (needs --kv-format rns8r)")
     ap.add_argument("--spec", default=None, metavar="DRAFTER[:K]",
                     help='speculative decoding drafter: "ngram[:k]" or '
                          '"rns[:k]" (greedy only; paged engines). Output '
                          "tokens are bit-identical to plain decoding")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def build_engine(args, *, s_max: int | None = None, **engine_kw):
+    """``(cfg, engine)`` for parsed ``args``: config (depth cut by
+    ``--layers``), model, seeded random weights, and the serving engine.
+    ``s_max`` defaults to the prompt plus the decode budget."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     # rns_impl=None: the repro.numerics backend registry auto-selects the
     # implementation by platform (pallas on TPU, interpret elsewhere)
     model = build_model(cfg, system=args.system)
+    params = model.init(jax.random.PRNGKey(args.seed))
+    if s_max is None:
+        s_max = args.prompt_len + args.max_new + 1
+        if cfg.family == "vlm":
+            s_max += cfg.n_img_tokens
+        if cfg.is_encdec:
+            s_max = args.prompt_len  # encoder memory; decoder len = dec_len
+    engine = ServingEngine(model, params, batch=args.batch, s_max=s_max,
+                           prepare=not args.no_prepare, spec=args.spec,
+                           kv_format=args.kv_format, policy=args.policy,
+                           **engine_kw)
+    return cfg, engine
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parser().parse_args(argv)
+    cfg, engine = build_engine(args)
     key = jax.random.PRNGKey(args.seed)
-    params = model.init(key)
-
     B, P = args.batch, args.prompt_len
-    s_max = P + args.max_new + 1
-    if cfg.family == "vlm":
-        s_max += cfg.n_img_tokens
-    if cfg.is_encdec:
-        s_max = P  # encoder memory length; decoder len = cfg.dec_len
-
-    engine = ServingEngine(model, params, batch=B, s_max=s_max,
-                           prepare=not args.no_prepare, spec=args.spec)
     rng = np.random.default_rng(args.seed)
     if cfg.is_encdec:
         from repro.models.frontends import synthetic_frames

@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.tokens import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.train.ft import FtConfig, run_training, run_with_restarts
 from repro.train.loop import make_train_step
@@ -30,6 +31,7 @@ __all__ = ["main"]
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--system", "--backend", dest="system", default="bns",

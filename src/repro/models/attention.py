@@ -208,9 +208,11 @@ def _core(q, k, v, *, causal: bool, q_pos, kv_pos, kv_mask=None,
     if mask is not None:
         scores = jnp.where(mask, scores, jnp.float32(-1e30))
     probs = jax.nn.softmax(scores, axis=-1)
-    pg = probs.reshape(B, Kv, G, Sq, T).astype(v.dtype)
-    out = jnp.einsum("bkgqt,btkd->bqkgd", pg, v)
-    out = out.reshape(B, Sq, H, hd)
+    # P.V in f32 like the flash kernels: rounding P to a bf16 cache dtype
+    # here but not there made the mesh and single-device steps disagree
+    pg = probs.reshape(B, Kv, G, Sq, T)
+    out = jnp.einsum("bkgqt,btkd->bqkgd", pg, v.astype(jnp.float32))
+    out = out.reshape(B, Sq, H, hd).astype(q.dtype)
     if not cache_mode:
         out = constrain_any(out, ("dp", None, "tp", None),
                             ("dp", "tp", None, None))

@@ -10,7 +10,7 @@ The flash kernels join the matmul runners on the registry axis
   backend is the materialized-score oracle (``kernels/ref.py``).
 * ``flash_decode`` — the split-KV decode schedule: KV chunks run as
   *parallel* grid steps emitting online-softmax partials, merged here by
-  :func:`merge_decode_partials` (a tiny (B, H, n_chunks)-sized jnp pass).
+  :func:`merge_decode_partials` (a tiny (B, n_chunks, H)-sized jnp pass).
 
 ``kv_len`` is a runtime ``(B,)`` operand on both ops — decode positions and
 ragged prompts share one compiled kernel (no per-position recompiles).
@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels import compat
 from repro.kernels.flash_attn import (
     DEFAULT_BLOCKS,
     flash_attention_pallas,
@@ -95,14 +94,14 @@ def merge_decode_partials(o_p: jax.Array, m_p: jax.Array,
                           l_p: jax.Array) -> jax.Array:
     """Log-sum-exp merge of split-KV partials.
 
-    o_p: (B, H, hd, n_chunks) f32;  m_p, l_p: (B, H, n_chunks) f32.
+    o_p: (B, n_chunks, H, hd) f32;  m_p, l_p: (B, n_chunks, H) f32.
     Returns (B, H, hd) f32.  All-masked chunks carry (o=0, m=-inf, l=0)
     and weigh out naturally (their exp(m - m_max) underflows to zero).
     """
-    m_max = jnp.max(m_p, axis=-1, keepdims=True)         # (B, H, 1)
-    w = jnp.exp(m_p - m_max)                             # (B, H, n_chunks)
-    l_tot = jnp.sum(l_p * w, axis=-1)                    # (B, H)
-    o = jnp.einsum("bhdc,bhc->bhd", o_p, w)
+    m_max = jnp.max(m_p, axis=1, keepdims=True)          # (B, 1, H)
+    w = jnp.exp(m_p - m_max)                             # (B, n_chunks, H)
+    l_tot = jnp.sum(l_p * w, axis=1)                     # (B, H)
+    o = jnp.einsum("bchd,bch->bhd", o_p, w)
     return o / jnp.maximum(l_tot, 1e-30)[..., None]
 
 
@@ -149,47 +148,39 @@ register_impl("flash_decode", "ref", _decode_ref_impl)
 register_impl("flash_decode", "cost", _decode_ref_impl)
 
 
-# flash_paged_decode: (q, k_raw, v_raw, k_scale, v_scale, k_wit, v_wit,
-#                      fmt, tab, kv_len, page_size) -> (out (B, H, hd) f32,
+# flash_paged_decode: (q, k_raw, v_raw, k_scale, v_scale, fmt, syndrome,
+#                      tab, kv_len, page_size) -> (out (B, H, hd) f32,
 #                      syn (B,) int32 | None)
 # k_raw/v_raw are the unwrapped pool leaves: (P, ps, Kv, hd) cache dtype for
-# dense pages, (P, ps, Kv, hd/vpb) uint8 planes (+ (P, ps, Kv, 1) f32
-# scales) for residue pages.  k_wit/v_wit are the redundant witness lanes
-# (P, ps, r, Kv, hd) uint8 when the caller asked for in-kernel syndrome
-# accumulation, else None.  fmt is the static KVFormat.
+# dense pages, (P, ps, 1 + r, Kv, hd/vpb) uint8 planes (+ (P, ps, Kv, 1) f32
+# scales) for residue pages — lane 0 the packed info byte, lanes 1..r the
+# redundant witnesses, read only under ``syndrome``.  fmt is the static
+# KVFormat.
 
 def _paged_kernel_impl(interpret: bool):
-    def run(q, k_raw, v_raw, k_scale, v_scale, k_wit, v_wit, fmt, tab,
-            kv_len, page_size):
+    def run(q, k_raw, v_raw, k_scale, v_scale, fmt, syndrome, tab, kv_len,
+            page_size):
         moduli = fmt.mset.info_moduli if fmt.is_residue else None
-        if k_wit is None:
-            # syndrome-free hot path: witness lanes are stripped by the
-            # dispatcher and never reach the kernel
-            o_p, m_p, l_p = flash_paged_decode_pallas(
-                q, k_raw, v_raw, tab, kv_len, page_size=page_size,
-                k_scale=k_scale, v_scale=v_scale, moduli=moduli,
-                interpret=interpret)
-            return merge_decode_partials(o_p, m_p, l_p), None
-        o_p, m_p, l_p, syn = flash_paged_decode_pallas(
+        red = fmt.mset.redundant_moduli if syndrome else None
+        outs = flash_paged_decode_pallas(
             q, k_raw, v_raw, tab, kv_len, page_size=page_size,
             k_scale=k_scale, v_scale=v_scale, moduli=moduli,
-            k_witness=k_wit, v_witness=v_wit,
-            red_moduli=fmt.mset.redundant_moduli,
-            interpret=interpret)
-        # nonzero only on GQA lead heads -> the sum counts each element once
-        return merge_decode_partials(o_p, m_p, l_p), syn.sum(axis=(1, 2))
+            red_moduli=red, interpret=interpret)
+        return merge_decode_partials(*outs[:3]), (outs[3] if syndrome
+                                                  else None)
     return run
 
 
-def _paged_ref_impl(q, k_raw, v_raw, k_scale, v_scale, k_wit, v_wit, fmt,
-                    tab, kv_len, page_size):
+def _paged_ref_impl(q, k_raw, v_raw, k_scale, v_scale, fmt, syndrome, tab,
+                    kv_len, page_size):
     """Oracle: gather the page list into a dense cache, dequantize, attend."""
     B, n_pmax = tab.shape
 
     def dense_of(raw, scale):
-        pages = raw[tab]                       # (B, n_pmax, ps, Kv, hd?)
+        pages = raw[tab]                       # (B, n_pmax, ps, ...)
         if fmt.is_residue:
-            vals = fmt.pack.decode(pages.astype(jnp.int32))
+            info = pages[:, :, :, 0].astype(jnp.int32)
+            vals = fmt.pack.decode(info)
             pages = vals.astype(jnp.float32) * scale[tab]
         return pages.reshape(B, n_pmax * page_size, *pages.shape[3:])
 
@@ -197,21 +188,21 @@ def _paged_ref_impl(q, k_raw, v_raw, k_scale, v_scale, k_wit, v_wit, fmt,
     v = dense_of(v_raw, v_scale)
     out = gqa_attention_ref(q[:, None], k, v, kv_len, causal=False)
     syn = None
-    if k_wit is not None:
-        syn = (_ref_syndrome(k_raw, k_wit, fmt, tab, kv_len, page_size)
-               + _ref_syndrome(v_raw, v_wit, fmt, tab, kv_len, page_size))
+    if syndrome:
+        syn = (_ref_syndrome(k_raw, fmt, tab, kv_len, page_size)
+               + _ref_syndrome(v_raw, fmt, tab, kv_len, page_size))
     return out[:, 0].astype(jnp.float32), syn
 
 
-def _ref_syndrome(raw, wit, fmt, tab, kv_len, page_size):
+def _ref_syndrome(raw, fmt, tab, kv_len, page_size):
     """Mirror of the kernel's witness check: per-request mismatch count."""
     B, n_pmax = tab.shape
-    vals = fmt.pack.decode(raw[tab].astype(jnp.int32))  # (B, np, ps, Kv, hd)
-    w = wit[tab].astype(jnp.int32)                      # (B, np, ps, r, Kv, hd)
+    pages = raw[tab].astype(jnp.int32)          # (B, np, ps, 1+r, Kv, hd)
+    vals = fmt.pack.decode(pages[:, :, :, 0])           # (B, np, ps, Kv, hd)
     mism = jnp.zeros(vals.shape, jnp.bool_)
     for jw, m in enumerate(fmt.mset.redundant_moduli):
         mism = mism | (jnp.remainder(
-            w[:, :, :, jw] - jnp.remainder(vals, m), m) != 0)
+            pages[:, :, :, 1 + jw] - jnp.remainder(vals, m), m) != 0)
     rows = (jnp.arange(n_pmax * page_size)
             .reshape(1, n_pmax, page_size, 1, 1))
     valid = rows < kv_len.reshape(B, 1, 1, 1, 1)
@@ -291,8 +282,8 @@ def flash_attention(
     def body(q_, k_, v_, *rest):
         return impl(q_, k_, v_, rest[0] if rest else None, causal, bq, bk)
 
-    return compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                            out_specs=bspec, check_vma=False)(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=bspec, check_vma=False)(*args)
 
 
 def flash_decode(
@@ -324,7 +315,7 @@ def flash_decode(
     def body(q_, k_, v_, len_):
         return impl(q_, k_, v_, len_, bk)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(qspec, kvspec, kvspec, P(dp or None)),
         out_specs=qspec, check_vma=False)(q, k, v, kv_len)
 
@@ -361,27 +352,18 @@ def paged_decode(
         raise ValueError(
             "syndrome=True requires a redundant residue KV format "
             f"(e.g. 'rns8r'); got {fmt.name!r}")
-    k_wit = v_wit = None
     if fmt.is_residue:
-        # lane 0 is always the packed info byte; redundant formats carry
-        # extra witness lanes that ride along only under syndrome=True
-        k_raw = jax.lax.index_in_dim(kv_layer.k.planes, 0, axis=-3,
-                                     keepdims=False)
-        v_raw = jax.lax.index_in_dim(kv_layer.v.planes, 0, axis=-3,
-                                     keepdims=False)
+        # the whole planes leaf goes to the kernel: lane 0 is the packed
+        # info byte, redundant witness lanes are read only under syndrome
+        k_raw, v_raw = kv_layer.k.planes, kv_layer.v.planes
         k_scale, v_scale = kv_layer.k.scale, kv_layer.v.scale
-        if syndrome:
-            k_wit = jax.lax.slice_in_dim(kv_layer.k.planes, 1,
-                                         1 + fmt.redundant, axis=-3)
-            v_wit = jax.lax.slice_in_dim(kv_layer.v.planes, 1,
-                                         1 + fmt.redundant, axis=-3)
     else:
         k_raw, v_raw = kv_layer.k, kv_layer.v
         k_scale = v_scale = None
     block_tab = jnp.asarray(block_tab, jnp.int32)
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (B,))
     impl = get_impl("flash_paged_decode", resolve_backend(backend))
-    out, syn = impl(q, k_raw, v_raw, k_scale, v_scale, k_wit, v_wit, fmt,
+    out, syn = impl(q, k_raw, v_raw, k_scale, v_scale, fmt, syndrome,
                     block_tab, kv_len, page_size)
     return (out, syn) if syndrome else out
 

@@ -24,7 +24,7 @@ Mesh composition
 :func:`tp_shard_plan` turns the installed
 :class:`~repro.parallel.sharding.ShardCtx` into a *static*, tagged
 shard-map plan; with a plan, :func:`rns_run` / :func:`sdrns_run` wrap
-their whole body in ``kernels/compat.shard_map``.  Two schedules:
+their whole body in ``jax.shard_map``.  Two schedules:
 
 * ``("col", ...)`` — the default layout: activations row-sharded over
   ``dp``, pre-encoded planes column-sharded over ``tp`` on the output
@@ -62,7 +62,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import sd, sdrns
 from repro.core.moduli import ModuliSet
-from repro.kernels import compat
 from repro.kernels.rns_matmul import rns_matmul_pallas
 from repro.kernels.sd_add import sd_add_pallas
 from repro.kernels.sdrns_matmul import (
@@ -164,7 +163,7 @@ def _shard_mapped(body, shard, *, sd_planes: bool):
     """Wrap a 2-operand runner body in a ``("col", ...)`` plan's shard_map."""
     _, mesh, dp, tp = shard
     b_spec = P(None, None, tp, None) if sd_planes else P(None, None, tp)
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp or None, None), b_spec),
         out_specs=P(dp or None, tp),
@@ -181,7 +180,7 @@ def _channel_mapped(body, shard, *, sd_planes: bool):
     tp_entry = tp if len(tp) > 1 else tp[0]
     b_spec = (P(tp_entry, None, None, None) if sd_planes
               else P(tp_entry, None, None))
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp or None, None), b_spec),
         out_specs=P(dp or None, None),
@@ -197,7 +196,7 @@ def _channel_ids(tp, C_loc: int) -> jax.Array:
     """
     idx = jax.lax.axis_index(tp[0])
     for name in tp[1:]:
-        idx = idx * compat.axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx * C_loc + jnp.arange(C_loc, dtype=jnp.int32)
 
 
@@ -284,7 +283,8 @@ register_impl("rns_matmul_planes", "cost", _rns_matmul_planes_ref_impl)
 
 
 def _res_dtype(mset: ModuliSet):
-    return jnp.int8 if max(mset.moduli) <= 257 else jnp.int32
+    # centered residues reach m // 2, which int8 holds only up to m = 255
+    return jnp.int8 if max(mset.moduli) <= 255 else jnp.int32
 
 
 def encode_rns_planes(w: jax.Array, mset: ModuliSet) -> jax.Array:

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.kernels import compat
 
 __all__ = ["init_error_state", "compressed_grad_mean", "make_compressed_mean"]
 
@@ -41,14 +40,14 @@ def init_error_state(grads: Any) -> Any:
 def _axis_prod(axes: tuple[str, ...]) -> int:
     n = 1
     for a in axes:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
     return n
 
 
 def _linear_axis_index(axes: tuple[str, ...]) -> jax.Array:
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -108,7 +107,7 @@ def make_compressed_mean(mesh: Mesh, axes: tuple[str, ...]):
         spec_in = jax.tree_util.tree_map(lambda _: P(*[None] * _.ndim), grads)
 
         @functools.partial(
-            compat.shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(spec_in, spec_in), out_specs=(spec_in, spec_in),
             check_vma=False)
         def inner(g, e):

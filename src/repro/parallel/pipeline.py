@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.kernels import compat
 
 __all__ = ["pipeline_apply"]
 
@@ -55,7 +54,7 @@ def pipeline_apply(
     x_spec = P(*[None] * x.ndim)
 
     @functools.partial(
-        compat.shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(p_specs, x_spec), out_specs=x_spec, check_vma=False)
     def run(local_params, xs):
         # local_params leaves: (1, ...) -> squeeze the stage dim

@@ -35,11 +35,22 @@ def quantize_symmetric(
       (q, scale): q int32 in [-qmax, qmax]; scale broadcastable to x so that
       ``q * scale ~= x``.
     """
-    qmax = qmax_for_bits(bits)
+    scale = symmetric_scale(x, bits, axis=axis)
+    return quantize_with_scale(x, scale, bits), scale.astype(jnp.float32)
+
+
+def symmetric_scale(x: jax.Array, bits: int, *,
+                    axis: int | tuple[int, ...] | None = None) -> jax.Array:
+    """The scale of :func:`quantize_symmetric`, in ``x``'s dtype."""
     amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
-    scale = jnp.maximum(amax, 1e-8) / qmax
-    q = jnp.clip(jnp.round(x / scale), -qmax, qmax).astype(jnp.int32)
-    return q, scale.astype(jnp.float32)
+    return jnp.maximum(amax, 1e-8) / qmax_for_bits(bits)
+
+
+def quantize_with_scale(x: jax.Array, scale: jax.Array,
+                        bits: int) -> jax.Array:
+    """Integer codes of ``x`` under a given symmetric ``scale``."""
+    qmax = qmax_for_bits(bits)
+    return jnp.clip(jnp.round(x / scale), -qmax, qmax).astype(jnp.int32)
 
 
 def dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:

@@ -36,6 +36,7 @@ performs zero weight conversions (tests/test_residency.py).
 from __future__ import annotations
 
 import collections
+import functools
 from typing import Any
 
 import jax
@@ -45,6 +46,7 @@ from repro import numerics as nx
 from repro.core.moduli import P21, ModuliSet
 from repro.numerics import ResidueTensor
 from repro.parallel import sharding
+from repro.quant.quant import quantize_with_scale, symmetric_scale
 
 __all__ = [
     "SYSTEM_LAYOUT",
@@ -86,6 +88,21 @@ def counters() -> dict[str, int]:
 # ---------------------------------------------------------------------------
 # Prepared parameter form.
 # ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("spec",))
+def _encode_scaled(w: jax.Array, scale: jax.Array,
+                   spec: nx.EncodeSpec) -> ResidueTensor:
+    """Quantize + forward-convert as one fused program per weight shape.
+
+    Eager encoding would materialize the int32 codes and all C int32
+    residue channels of a whole layer stack before narrowing them to int8
+    — several GB at published widths.  The scale arrives computed outside
+    (eagerly, as the per-call path computes it), so the codes are
+    bit-identical to :func:`repro.quant.quant.quantize_symmetric`.
+    """
+    q = quantize_with_scale(w, scale, spec.qbits)
+    return nx.encode(q, spec, scale=scale)
 
 
 def prepare_weight(
@@ -138,7 +155,9 @@ def prepare_weight(
     if w.ndim < 2:
         raise ValueError(f"dense weight must be at least 2-D, got {w.shape}")
     spec = nx.EncodeSpec(layout=SYSTEM_LAYOUT[system], mset=mset, qbits=bits)
-    t = nx.encode(w.astype(jnp.float32), spec)
+    w = w.astype(jnp.float32)
+    scale = symmetric_scale(w, bits, axis=spec.quant_axis)
+    t = _encode_scaled(w, scale, spec)
     ctx = sharding.get_shard_ctx()
     if ctx is not None and roles is not False:
         if roles is None:  # generic dense rule: FSDP on K, TP on N

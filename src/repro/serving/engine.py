@@ -111,6 +111,7 @@ class ServingEngine:
         Paged KV serving (the default where supported): ``paged=None``
         enables the block-table page pool whenever the family has a paged
         decode path, the fused loop is on, and no mesh is installed;
+        ``paged=True`` demands it and raises where it is unsupported;
         ``paged=False`` pins the dense contiguous cache.  ``page_size``
         is the page length in tokens (== the split-KV flash-decode chunk);
         ``kv_format`` picks the page storage — ``"bf16"`` (bit-identical
@@ -193,11 +194,11 @@ class ServingEngine:
         if paged is None:
             paged = supported
         elif paged and not supported:
-            logger.info("paged serving unsupported here (fused_loop=%s, "
-                        "family=%s, mesh=%s) — falling back to dense",
-                        fused_loop, model.cfg.family,
-                        get_shard_ctx() is not None)
-            paged = False
+            raise ValueError(
+                f"paged serving is unsupported here (fused_loop={fused_loop}, "
+                f"family={model.cfg.family!r}, mesh="
+                f"{get_shard_ctx() is not None}); pass paged=None to let the "
+                "engine choose, or paged=False for the dense cache")
         self.paged = paged
         self.page_size = page_size
         self.kv_format = kv_format
@@ -280,10 +281,7 @@ class ServingEngine:
             fn = self._fused_spec
         else:
             fn = self._fused_paged if self.paged else self._fused
-        try:
-            return fn._cache_size()
-        except AttributeError:      # pragma: no cover - older jax
-            return -1
+        return fn._cache_size()
 
     def _pick_bucket(self, kind: str, n: int) -> int:
         """Bucket cap for a decode loop of length ``n``, reusing traces.

@@ -1,10 +1,1 @@
-"""Test-support utilities that must work offline.
-
-``hypothesis_shim`` is a minimal, deterministic stand-in for the subset of
-the ``hypothesis`` API this repo's property tests use; ``conftest.py``
-installs it only when the real package is unavailable (no network in the CI
-container).
-"""
-from repro.testing import hypothesis_shim
-
-__all__ = ["hypothesis_shim"]
+"""Test-support utilities (fault injection: :mod:`repro.testing.faults`)."""

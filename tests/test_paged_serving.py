@@ -364,3 +364,14 @@ def test_continuous_eos_mid_page(small_model):
     assert len(out[1].result) == 12
     assert out[0].stats.pages_freed > 0
     assert eng.pool.free_pages > 0
+
+
+def test_paged_true_raises_where_unsupported():
+    """paged=True is a demand: a family without a paged decode path (or a
+    host loop, or a mesh) is an error, not a quiet dense fallback."""
+    cfg = get_config("mamba2-780m").reduced()
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="paged serving is unsupported"):
+        ServingEngine(model, params, batch=2, s_max=16, paged=True)
+    assert not ServingEngine(model, params, batch=2, s_max=16).paged

@@ -1,0 +1,128 @@
+"""Compile rehearsals: the main-path Pallas kernels at yi-6b widths, compiled
+for a *described* (not attached) TPU v5e chip.
+
+Interpret mode runs any block shape, so it cannot see what Mosaic refuses:
+blocks whose last two dimensions are neither (8, 128)-aligned nor whole,
+rank-1 blocks, transposed masks, fast-memory overuse.  Each test lowers and
+compiles one kernel at the published widths (d_model 4096, 32 query / 4 KV
+heads of 128, d_ff 11008) through the TPU compiler that ships with libtpu,
+and checks that the kernel survived as a ``tpu_custom_call``.
+
+The topology is described inside a module fixture, never at import time:
+only one process may hold libtpu, and pytest-xdist workers import every
+test module.  The persistent compilation cache is off around the compiles
+(an entry written for a described chip cannot be read back without one).
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.moduli import KV8, KV8R2
+from repro.kernels.flash_attn import (
+    flash_attention_pallas,
+    flash_decode_pallas,
+    flash_paged_decode_pallas,
+)
+from repro.kernels.rns_matmul import rns_matmul_pallas
+from repro.kernels.sdrns_matmul import sdrns_matmul_pallas, sdrns_matvec_pallas
+from repro.numerics.runners import _choose_blocks
+
+D_MODEL, D_FF, H, KV, HD = 4096, 11008, 32, 4, 128
+BATCH, PAGE, N_PMAX = 8, 64, 9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("M,K,N", [(BATCH, D_MODEL, D_MODEL),   # decode q/o
+                                   (BATCH, D_FF, D_MODEL),      # decode down
+                                   (4096, D_MODEL, D_FF)])      # prefill up
+def test_rns_matmul_compiles(one_chip, M, K, N):
+    bm, bn, bk = _choose_blocks(M, N, K)
+    K = -(-K // bk) * bk                 # the runner pads K to the tile
+    _compile(lambda a, b, m: rns_matmul_pallas(a, b, m, bm=bm, bn=bn, bk=bk,
+                                               interpret=False),
+             one_chip, ((3, M, K), jnp.int8), ((3, K, N), jnp.int8),
+             ((3,), jnp.int32))
+
+
+def test_flash_attention_compiles(one_chip):
+    S = 512
+    _compile(lambda q, k, v, n: flash_attention_pallas(q, k, v, n,
+                                                       interpret=False),
+             one_chip, ((1, S, H, HD), jnp.bfloat16),
+             ((1, S, KV, HD), jnp.bfloat16), ((1, S, KV, HD), jnp.bfloat16),
+             ((1,), jnp.int32))
+
+
+def test_flash_decode_compiles(one_chip):
+    T = 1024
+    _compile(lambda q, k, v, n: flash_decode_pallas(q, k, v, n, bk=512,
+                                                    interpret=False),
+             one_chip, ((BATCH, H, HD), jnp.bfloat16),
+             ((BATCH, T, KV, HD), jnp.bfloat16),
+             ((BATCH, T, KV, HD), jnp.bfloat16), ((BATCH,), jnp.int32))
+
+
+@pytest.mark.parametrize("fmt,syndrome", [(None, False), ("rns8", False),
+                                          ("rns8r", False), ("rns8r", True)])
+def test_paged_decode_compiles(one_chip, fmt, syndrome):
+    P = 1 + BATCH * N_PMAX
+    common = [((BATCH, H, HD), jnp.bfloat16)]
+    tab = [((BATCH, N_PMAX), jnp.int32), ((BATCH,), jnp.int32)]
+    if fmt is None:
+        pool = ((P, PAGE, KV, HD), jnp.bfloat16)
+        _compile(lambda q, k, v, t, n: flash_paged_decode_pallas(
+                     q, k, v, t, n, page_size=PAGE, interpret=False),
+                 one_chip, *common, pool, pool, *tab)
+        return
+    mset = {"rns8": KV8, "rns8r": KV8R2}[fmt]
+    planes = ((P, PAGE, 1 + mset.redundant, KV, HD), jnp.uint8)
+    scale = ((P, PAGE, KV, 1), jnp.float32)
+    red = mset.redundant_moduli if syndrome else None
+    _compile(lambda q, k, v, t, n, ks, vs: flash_paged_decode_pallas(
+                 q, k, v, t, n, page_size=PAGE, k_scale=ks, v_scale=vs,
+                 moduli=mset.info_moduli, red_moduli=red, interpret=False),
+             one_chip, *common, planes, planes, *tab, scale, scale)
+
+
+@pytest.mark.parametrize("kernel", [sdrns_matmul_pallas, sdrns_matvec_pallas])
+def test_sdrns_kernels_refuse_mosaic(kernel):
+    """The fused SD-RNS kernel is interpreter-only: asking for Mosaic is a
+    clear error, never a silent fallback."""
+    n = 7
+    a = jnp.zeros((3, 8, 128, n), jnp.int8)
+    b = jnp.zeros((3, 128, 128, n), jnp.int8)
+    ws = jnp.zeros((3,), jnp.int32)
+    kw = {"bn": 128} if kernel is sdrns_matvec_pallas else {"bm": 8,
+                                                            "bn": 128}
+    with pytest.raises(NotImplementedError, match="not Mosaic-legal"):
+        kernel(a, b, ws, interpret=False, **kw)

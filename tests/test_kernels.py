@@ -149,4 +149,3 @@ def test_sd_add_batch_shapes():
     np.testing.assert_array_equal(
         np.asarray(sd.to_int(got)) % m, np.asarray(sd.to_int(want)) % m
     )
-

@@ -4,7 +4,7 @@
     python chip_smoke.py --chips 4    # channel-parallel decode on 3 of 4
 
 One chip.  ``yi-6b`` at its published widths (d_model 4096, 32 query / 4 KV
-heads of 128, d_ff 11008, vocab 64000) with the depth cut to ``--layers``,
+heads of 128, d_ff 11008, vocab 64000) with the depth cut to :data:`LAYERS`,
 random weights from ``--seed``, ``system="rns"`` (P21, 4-bit,
 residue-resident).  Eight seeded requests (prompts of 128-512 tokens,
 ``max_new`` 32) go through ``RequestScheduler.serve`` on the paged engine
@@ -56,8 +56,13 @@ PAGE = 64
 # max |logits - ref| over max |ref|, per compared logits block.  Both paths
 # compute the same exact residue matmuls; they differ in attention
 # summation order and matmul precision, which 4-bit activation
-# quantization can amplify by a code step here and there.
+# quantization can amplify by a code step here and there.  Planted faults
+# land far above it (CPU interpreter, reduced widths: query heads paired
+# with the wrong KV head in the paged kernel 1.3, bf16 P·V 0.29).
 LOGIT_TOL = 2e-2
+# every request's greedy stream agrees with ref on the prefill token and the
+# fused loop's first decode token
+MIN_AGREED = 2
 DECODE_STEPS = 4        # --chips 4: decode steps compared
 
 
@@ -171,6 +176,11 @@ def run_serving(layers: int, seed: int, arch_flags=(),
     if not (np.isfinite(pre).all() and np.isfinite(dec).all()):
         raise RuntimeError("non-finite logits")
     log("logits_shape", (pre.shape, dec.shape))
+    # the served stream opens with the probed logits' argmaxes: prefill's,
+    # then the fused loop's first paged decode step's
+    probed = np.stack([tok, dec.argmax(-1)], axis=1)
+    if not np.array_equal(np.stack([r.result[:2] for r in served]), probed):
+        raise RuntimeError("fused-loop tokens disagree with the probed logits")
 
     # the same requests with every kernel pinned to its jnp reference
     model_ref = build_model(cfg, system="rns", rns_impl="ref")
@@ -196,11 +206,14 @@ def run_serving(layers: int, seed: int, arch_flags=(),
     log("prefill_logits_rel_err", e_pre)
     log("decode_logits_rel_err", e_dec)
     log("logit_tol", LOGIT_TOL)
-    log("greedy_agreed_tokens",
-        [agreed_prefix(a.result, b.result) for a, b in zip(served,
-                                                           ref_served)])
+    agreed = [agreed_prefix(a.result, b.result)
+              for a, b in zip(served, ref_served)]
+    log("greedy_agreed_tokens", agreed)
     if e_pre > LOGIT_TOL or e_dec > LOGIT_TOL:
         raise RuntimeError(f"logits differ from ref beyond {LOGIT_TOL}")
+    if min(agreed) < MIN_AGREED:
+        raise RuntimeError(f"served tokens agree with ref for {min(agreed)} "
+                           f"< {MIN_AGREED} steps")
 
 
 def count_tp_psums(hlo: str, tp_size: int, layers: int) -> int:
@@ -300,7 +313,6 @@ def run_channel_shard(layers: int, seed: int, arch_flags=()) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
-    ap.add_argument("--layers", type=int, default=LAYERS)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -320,9 +332,9 @@ def main(argv=None) -> int:
                            f"devices, JAX sees {n}")
     t0 = time.perf_counter()
     if args.chips == 4:
-        run_channel_shard(args.layers, args.seed)
+        run_channel_shard(LAYERS, args.seed)
     else:
-        run_serving(args.layers, args.seed)
+        run_serving(LAYERS, args.seed)
     log("wall_s", round(time.perf_counter() - t0, 3))
     log("compile_cache", compile_stats())
     log("peak_bytes_in_use", [d.memory_stats().get("peak_bytes_in_use")

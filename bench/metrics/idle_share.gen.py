@@ -1,0 +1,8 @@
+"""Device idle share: 1 - the union of device-op intervals over the traced
+window."""
+
+
+def read(run):
+    if run.trace is None or not run.trace.window_s:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)

@@ -1,0 +1,11 @@
+"""The harness's own tests, run by hand on the CPU:
+
+    JAX_PLATFORMS=cpu python -m pytest bench/tests -q
+"""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH, BENCH.parent / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))

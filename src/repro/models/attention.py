@@ -144,16 +144,20 @@ def init_kv_cache(batch: int, s_max: int, n_kv: int, head_dim: int,
 def _project_qkv(params, x, *, n_heads, n_kv, head_dim, qk_norm, positions,
                  rope_theta, dense_kw, apply_rope=True):
     B, S, _ = x.shape
-    q = linear.dense(params["wq"], x, **dense_kw).reshape(B, S, n_heads,
-                                                          head_dim)
-    k = linear.dense(params["wk"], x, **dense_kw).reshape(B, S, n_kv, head_dim)
-    v = linear.dense(params["wv"], x, **dense_kw).reshape(B, S, n_kv, head_dim)
-    if qk_norm:
-        q = rmsnorm(params["q_norm"], q)
-        k = rmsnorm(params["k_norm"], k)
+    with jax.named_scope("attn.qkv"):
+        q = linear.dense(params["wq"], x, **dense_kw).reshape(
+            B, S, n_heads, head_dim)
+        k = linear.dense(params["wk"], x, **dense_kw).reshape(
+            B, S, n_kv, head_dim)
+        v = linear.dense(params["wv"], x, **dense_kw).reshape(
+            B, S, n_kv, head_dim)
+        if qk_norm:
+            q = rmsnorm(params["q_norm"], q)
+            k = rmsnorm(params["k_norm"], k)
     if apply_rope:
-        q = rope(q, positions, theta=rope_theta)
-        k = rope(k, positions, theta=rope_theta)
+        with jax.named_scope("attn.rope"):
+            q = rope(q, positions, theta=rope_theta)
+            k = rope(k, positions, theta=rope_theta)
     q = constrain(q, "dp", None, "tp", None)
     return q, k, v
 
@@ -319,13 +323,16 @@ def prefill_attention(params, x, s_max: int, *, cache_dtype=jnp.bfloat16,
         rope_theta=kw.get("rope_theta", 1e4), dense_kw=dense_kw,
         apply_rope=kw.get("apply_rope", True),
     )
-    pad = [(0, 0), (0, s_max - S), (0, 0), (0, 0)]
-    cache = KVCache(jnp.pad(k.astype(cache_dtype), pad),
-                    jnp.pad(v.astype(cache_dtype), pad))
+    with jax.named_scope("kv.layer"):
+        pad = [(0, 0), (0, s_max - S), (0, 0), (0, 0)]
+        cache = KVCache(jnp.pad(k.astype(cache_dtype), pad),
+                        jnp.pad(v.astype(cache_dtype), pad))
     causal = kw.get("causal", True)
-    out = _full_seq(q, k, v, causal=causal, pos1d=positions,
-                    n_heads=n_heads, head_dim=head_dim)
-    return linear.dense(params["wo"], out, **dense_kw), cache
+    with jax.named_scope("attn.core"):
+        out = _full_seq(q, k, v, causal=causal, pos1d=positions,
+                        n_heads=n_heads, head_dim=head_dim)
+    with jax.named_scope("attn.out"):
+        return linear.dense(params["wo"], out, **dense_kw), cache
 
 
 def decode_attention(
@@ -434,20 +441,24 @@ def paged_decode_attention(
                            positions=positions, rope_theta=rope_theta,
                            dense_kw=dense_kw, apply_rope=apply_rope)
     n_pmax = block_tab.shape[1]
-    page_idx = jnp.clip(pos // page_size, 0, n_pmax - 1)
-    pages = jnp.take_along_axis(block_tab, page_idx[:, None], axis=1)[:, 0]
-    offs = pos % page_size
-    kv_layer = nxkv.append_token(kv_layer,
-                                 k[:, 0].astype(cache_dtype),
-                                 v[:, 0].astype(cache_dtype), pages, offs)
+    with jax.named_scope("kv.layer"):
+        page_idx = jnp.clip(pos // page_size, 0, n_pmax - 1)
+        pages = jnp.take_along_axis(block_tab, page_idx[:, None],
+                                    axis=1)[:, 0]
+        offs = pos % page_size
+        kv_layer = nxkv.append_token(kv_layer,
+                                     k[:, 0].astype(cache_dtype),
+                                     v[:, 0].astype(cache_dtype), pages, offs)
     backend = _paged_backend(B, n_heads, n_pmax)
-    o = nxattn.paged_decode(q[:, 0], kv_layer, block_tab, kv_len=pos + 1,
-                            page_size=page_size, backend=backend,
-                            syndrome=with_syndrome)
+    with jax.named_scope("attn.core"):
+        o = nxattn.paged_decode(q[:, 0], kv_layer, block_tab,
+                                kv_len=pos + 1, page_size=page_size,
+                                backend=backend, syndrome=with_syndrome)
     if with_syndrome:
         o, syn = o
-    out = o.astype(q.dtype).reshape(B, 1, n_heads * head_dim)
-    out = linear.dense(params["wo"], out, **dense_kw)
+    with jax.named_scope("attn.out"):
+        out = o.astype(q.dtype).reshape(B, 1, n_heads * head_dim)
+        out = linear.dense(params["wo"], out, **dense_kw)
     if with_syndrome:
         return out, kv_layer, syn
     return out, kv_layer
@@ -498,15 +509,19 @@ def paged_verify_attention(
                            positions=positions, rope_theta=rope_theta,
                            dense_kw=dense_kw, apply_rope=apply_rope)
     n_pmax = block_tab.shape[1]
-    page_idx = positions // page_size
-    pages = jnp.take_along_axis(block_tab,
-                                jnp.clip(page_idx, 0, n_pmax - 1), axis=1)
-    pages = jnp.where(page_idx < n_pmax, pages, 0)   # overshoot -> dump
-    offs = positions % page_size
-    kv_layer = nxkv.append_token(kv_layer, k.astype(cache_dtype),
-                                 v.astype(cache_dtype), pages, offs)
+    with jax.named_scope("kv.layer"):
+        page_idx = positions // page_size
+        pages = jnp.take_along_axis(block_tab,
+                                    jnp.clip(page_idx, 0, n_pmax - 1), axis=1)
+        pages = jnp.where(page_idx < n_pmax, pages, 0)   # overshoot -> dump
+        offs = positions % page_size
+        kv_layer = nxkv.append_token(kv_layer, k.astype(cache_dtype),
+                                     v.astype(cache_dtype), pages, offs)
     backend = _paged_backend(B * V, n_heads, n_pmax)
-    o = nxattn.paged_verify(q, kv_layer, block_tab, kv_len=positions + 1,
-                            page_size=page_size, backend=backend)
-    out = o.astype(q.dtype).reshape(B, V, n_heads * head_dim)
-    return linear.dense(params["wo"], out, **dense_kw), kv_layer
+    with jax.named_scope("attn.core"):
+        o = nxattn.paged_verify(q, kv_layer, block_tab,
+                                kv_len=positions + 1, page_size=page_size,
+                                backend=backend)
+    with jax.named_scope("attn.out"):
+        out = o.astype(q.dtype).reshape(B, V, n_heads * head_dim)
+        return linear.dense(params["wo"], out, **dense_kw), kv_layer

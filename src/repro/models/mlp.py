@@ -30,10 +30,12 @@ def init_swiglu(key: jax.Array, d_model: int, d_ff: int,
 def swiglu(params: dict[str, Any], x: jax.Array,
            dense_kw: dict[str, Any] | None = None) -> jax.Array:
     dense_kw = dense_kw or {}
-    g = linear.dense(params["w_gate"], x, **dense_kw)
-    u = linear.dense(params["w_up"], x, **dense_kw)
-    h = jax.nn.silu(g.astype(jnp.float32)).astype(u.dtype) * u
-    return linear.dense(params["w_down"], h, **dense_kw)
+    with jax.named_scope("mlp.gate_up"):
+        g = linear.dense(params["w_gate"], x, **dense_kw)
+        u = linear.dense(params["w_up"], x, **dense_kw)
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(u.dtype) * u
+    with jax.named_scope("mlp.down"):
+        return linear.dense(params["w_down"], h, **dense_kw)
 
 
 def init_gelu_mlp(key: jax.Array, d_model: int, d_ff: int,

@@ -31,6 +31,14 @@ leaves, moduli/layout/qbits as static metadata), and the same
 decode step then performs zero weight quantize/forward-convert work — MoE
 expert stacks and the tied-embedding logits matmul included (the
 conversion-free steady state the serving engine relies on).
+
+Device scopes: the serving paths (prefill, paged decode and verify, the
+logits head) wrap each sub-layer in a ``jax.named_scope`` — ``embed``,
+``attn.norm``, ``attn.qkv``, ``attn.rope``, ``attn.core``, ``attn.out``,
+``kv.layer``, ``mlp.norm``, ``mlp.gate_up``, ``mlp.down``, ``logits`` (and
+``sample`` in the engine) — so each device op's name stack says which
+sub-layer made it.  Ops under none of them (the layer scan's own slicing
+and stacking) are the scan's plumbing.  Scopes change op metadata only.
 """
 from __future__ import annotations
 
@@ -130,6 +138,7 @@ def init_lm(key: jax.Array, cfg: ArchConfig) -> dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("embed")
 def _embed_inputs(params, cfg: ArchConfig, tokens: jax.Array,
                   patches: jax.Array | None, compute_dtype) -> jax.Array:
     x = params["embed"]["table"].astype(compute_dtype)[tokens]
@@ -138,6 +147,7 @@ def _embed_inputs(params, cfg: ArchConfig, tokens: jax.Array,
     return constrain(x, "dp", "seq", None)
 
 
+@jax.named_scope("logits")
 def _logits(params, cfg: ArchConfig, x: jax.Array,
             dense_kw: dict[str, Any] | None = None) -> jax.Array:
     """Logits in compute dtype (softmax/CE upcast to f32 downstream).
@@ -165,6 +175,12 @@ def _logits(params, cfg: ArchConfig, x: jax.Array,
         logits = jnp.matmul(x, params["embed"]["table"].astype(x.dtype).T,
                             preferred_element_type=x.dtype)
     return constrain(logits, "dp", None, "tp")
+
+
+def _norm(scope: str, params, x: jax.Array) -> jax.Array:
+    """``rmsnorm`` under the device scope ``scope``."""
+    with jax.named_scope(scope):
+        return rmsnorm(params, x)
 
 
 # ---------------------------------------------------------------------------
@@ -359,10 +375,10 @@ def lm_prefill(
     if cfg.family in ("dense", "moe", "vlm"):
         def body(x, lp):
             h, c2 = attn_mod.prefill_attention(
-                lp["attn"], rmsnorm(lp["attn_norm"], x), s_max,
+                lp["attn"], _norm("attn.norm", lp["attn_norm"], x), s_max,
                 cache_dtype=cache_dtype, **akw)
             x = x + h
-            h = rmsnorm(lp["mlp_norm"], x)
+            h = _norm("mlp.norm", lp["mlp_norm"], x)
             if cfg.family == "moe":
                 h, _ = moe_mod.moe(lp["moe"], h, n_experts=cfg.n_experts,
                                    top_k=cfg.top_k,
@@ -600,8 +616,9 @@ def lm_decode_paged(
             f"paged decode supports dense/moe/vlm, not {cfg.family!r}")
     dense_kw = dense_kw or {}
     compute_dtype = jnp.dtype(cfg.compute_dtype)
-    x = params["embed"]["table"].astype(compute_dtype)[token]  # (B, 1, d)
-    x = constrain(x, "dp", None, None)
+    with jax.named_scope("embed"):
+        x = params["embed"]["table"].astype(compute_dtype)[token]  # (B,1,d)
+        x = constrain(x, "dp", None, None)
     akw = dict(n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
                qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta,
                dense_kw=dense_kw, apply_rope=not cfg.is_encdec)
@@ -610,18 +627,20 @@ def lm_decode_paged(
     def body(carry, inp):
         x, kv = carry
         i, lp = inp
-        lay = kvp.layer_slice(kv, i)
+        with jax.named_scope("kv.layer"):
+            lay = kvp.layer_slice(kv, i)
         att = attn_mod.paged_decode_attention(
-            lp["attn"], rmsnorm(lp["attn_norm"], x), lay, block_tab, pos,
-            page_size=page_size, cache_dtype=cache_dtype,
+            lp["attn"], _norm("attn.norm", lp["attn_norm"], x), lay,
+            block_tab, pos, page_size=page_size, cache_dtype=cache_dtype,
             with_syndrome=with_syndrome, **akw)
         if with_syndrome:
             h, lay2, syn = att
         else:
             (h, lay2), syn = att, None
-        kv = kvp.layer_update(kv, i, lay2)
+        with jax.named_scope("kv.layer"):
+            kv = kvp.layer_update(kv, i, lay2)
         x = x + h
-        h = rmsnorm(lp["mlp_norm"], x)
+        h = _norm("mlp.norm", lp["mlp_norm"], x)
         if cfg.family == "moe":
             h, _ = moe_mod.moe(lp["moe"], h, n_experts=cfg.n_experts,
                                top_k=cfg.top_k, capacity_factor=cfg.moe_cf,
@@ -674,8 +693,9 @@ def lm_verify_paged(
     dense_kw = dense_kw or {}
     compute_dtype = jnp.dtype(cfg.compute_dtype)
     V = tokens.shape[1]
-    x = params["embed"]["table"].astype(compute_dtype)[tokens]  # (B, V, d)
-    x = constrain(x, "dp", None, None)
+    with jax.named_scope("embed"):
+        x = params["embed"]["table"].astype(compute_dtype)[tokens]  # (B,V,d)
+        x = constrain(x, "dp", None, None)
     positions = jnp.asarray(pos, jnp.int32)[:, None] + jnp.arange(
         V, dtype=jnp.int32)[None, :]
     akw = dict(n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.hd,
@@ -686,13 +706,16 @@ def lm_verify_paged(
     def body(carry, inp):
         x, kv = carry
         i, lp = inp
-        lay = kvp.layer_slice(kv, i)
+        with jax.named_scope("kv.layer"):
+            lay = kvp.layer_slice(kv, i)
         h, lay2 = attn_mod.paged_verify_attention(
-            lp["attn"], rmsnorm(lp["attn_norm"], x), lay, block_tab,
-            positions, page_size=page_size, cache_dtype=cache_dtype, **akw)
-        kv = kvp.layer_update(kv, i, lay2)
+            lp["attn"], _norm("attn.norm", lp["attn_norm"], x), lay,
+            block_tab, positions, page_size=page_size,
+            cache_dtype=cache_dtype, **akw)
+        with jax.named_scope("kv.layer"):
+            kv = kvp.layer_update(kv, i, lay2)
         x = x + h
-        h = rmsnorm(lp["mlp_norm"], x)
+        h = _norm("mlp.norm", lp["mlp_norm"], x)
         if cfg.family == "moe":
             h, _ = moe_mod.moe(lp["moe"], h, n_experts=cfg.n_experts,
                                top_k=cfg.top_k, capacity_factor=cfg.moe_cf,

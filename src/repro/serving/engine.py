@@ -51,6 +51,7 @@ from repro.serving.kv_pool import KVPagePool
 from repro.serving.spec import SpecConfig, accept_blocks
 from repro.serving.stats import (EngineStats, RequestStats, SpecStats,
                                  deprecated_stat)
+from repro.serving.trace import Tracer
 
 __all__ = ["ServingEngine", "GenerateResult", "SegmentResult"]
 
@@ -182,6 +183,9 @@ class ServingEngine:
                 f"scrub must be 'off', 'decode' or 'rotate:k', got {scrub!r}")
         self.scrub = scrub
         self.stats = EngineStats()
+        # host spans of admission and segments (repro.serving.trace); set
+        # ``engine.tracer = Tracer(...)`` to record them
+        self.tracer = Tracer(enabled=False)
         # Baseline for the channel_shard fallback counter: the runner-level
         # count is process-lifetime, the stat is engine-lifetime.
         self._fallback_base = runners.fallback_gather_count()
@@ -564,6 +568,7 @@ class ServingEngine:
         B = tok0.shape[0]
         buf0 = jnp.zeros((B, max_new_cap), jnp.int32)
 
+        @jax.named_scope("sample")
         def sample(logits, step):
             if greedy:
                 t = jnp.argmax(logits, axis=-1)
@@ -641,6 +646,7 @@ class ServingEngine:
                  | (remaining <= 0))
         fin0 = done0
 
+        @jax.named_scope("sample")
         def sample(logits, step):
             if greedy:
                 t = jnp.argmax(logits, axis=-1)
@@ -766,29 +772,39 @@ class ServingEngine:
                              "acceptance only; run with temperature=0")
         cap = self._pick_bucket("spec" if self._drafter is not None
                                 else "paged", seg)
-        # scrub is *launched* (repaired arrays swapped in, counts left on
-        # device) and drained only after the decode dispatch is enqueued —
-        # the device orders scrub before decode via the data dependency,
-        # the host never blocks between them (DESIGN.md §15)
-        scrub_pending = self._scrub_launch()
-        eos_dev = jnp.asarray(np.clip(eos_vec, -1, 2**31 - 1), jnp.int32)
+        tr = self.tracer
+        with tr.span("segment.prepare"):
+            # scrub is *launched* (repaired arrays swapped in, counts left
+            # on device) and drained only after the decode dispatch is
+            # enqueued — the device orders scrub before decode via the data
+            # dependency, the host never blocks between them (DESIGN.md §15)
+            scrub_pending = self._scrub_launch()
+            tok_dev = jnp.asarray(tok0, jnp.int32)
+            eos_dev = jnp.asarray(np.clip(eos_vec, -1, 2**31 - 1), jnp.int32)
+            tab_dev = jnp.asarray(tabs, jnp.int32)
+            pos_dev = jnp.asarray(pos0, jnp.int32)
+            done_dev = jnp.asarray(done0)
+            rem_dev = jnp.asarray(remaining, jnp.int32)
+            key_dev = key if key is not None else jax.random.PRNGKey(0)
+        B = tok_dev.shape[0]
         if self._drafter is not None:
-            buf, cnt, steps, kv, dstate, done, prop, acc = self._fused_spec(
-                self.params, tok0, self.pool.kv, self._spec_state,
-                jnp.asarray(tabs, jnp.int32),
-                jnp.asarray(pos0, jnp.int32), eos_dev,
-                jnp.asarray(done0),
-                jnp.asarray(remaining, jnp.int32),
-                jnp.int32(seg), jnp.bool_(stop_on_finish),
-                seg_cap=cap)
-            self.pool.kv = kv          # donated in, aliased out
-            self._spec_state = dstate  # ditto (drafter KV / history)
-            self._note_fused_dispatch(cap)
-            self._last_scrub = self._drain_scrub(scrub_pending)
-            self._last_recompute = np.zeros(tok0.shape[0], bool)
-            counts = np.asarray(cnt)   # the single host sync of the segment
-            steps, prop, acc = int(steps), int(prop), int(acc)
-            n = int(counts.max()) if counts.size else 0
+            with tr.span("segment.dispatch"):
+                (buf, cnt, steps, kv, dstate, done, prop,
+                 acc) = self._fused_spec(
+                    self.params, tok_dev, self.pool.kv, self._spec_state,
+                    tab_dev, pos_dev, eos_dev, done_dev, rem_dev,
+                    jnp.int32(seg), jnp.bool_(stop_on_finish), seg_cap=cap)
+                self.pool.kv = kv          # donated in, aliased out
+                self._spec_state = dstate  # ditto (drafter KV / history)
+                self._note_fused_dispatch(cap)
+            with tr.span("segment.wait"):
+                self._last_scrub = self._drain_scrub(scrub_pending)
+                counts = np.asarray(cnt)   # the single host sync
+            self._last_recompute = np.zeros(B, bool)
+            with tr.span("segment.readback"):
+                steps, prop, acc = int(steps), int(prop), int(acc)
+                n = int(counts.max()) if counts.size else 0
+                toks, done = np.asarray(buf)[:, :n], np.asarray(done)
             self.stats.decode_steps += steps
             self.stats.decode_dispatches += 1
             self._sync_fallback_gathers()
@@ -798,13 +814,7 @@ class ServingEngine:
             sp.emitted += int(counts.sum())
             sp.verify_steps += steps
             sp.blocks += prop // self._drafter.k
-            return (np.asarray(buf)[:, :n], steps, np.asarray(done),
-                    counts, prop, acc)
-        tab_dev = jnp.asarray(tabs, jnp.int32)
-        pos_dev = jnp.asarray(pos0, jnp.int32)
-        done_dev = jnp.asarray(done0)
-        rem_dev = jnp.asarray(remaining, jnp.int32)
-        key_dev = key if key is not None else jax.random.PRNGKey(0)
+            return toks, steps, done, counts, prop, acc
 
         def run_once():
             # same operands every time: a replay after an in-place page
@@ -812,28 +822,36 @@ class ServingEngine:
             # run (the in-kernel syndrome fires *after* the faulty read, so
             # the first run's tokens are untrusted once syn != 0)
             return self._fused_paged(
-                self.params, tok0, self.pool.kv, tab_dev, pos_dev, eos_dev,
+                self.params, tok_dev, self.pool.kv, tab_dev, pos_dev, eos_dev,
                 done_dev, rem_dev, jnp.float32(temperature), key_dev,
                 jnp.int32(seg), jnp.int32(key_base),
                 jnp.bool_(stop_on_finish), seg_cap=cap, greedy=greedy)
 
-        buf, n, steps, kv, done, syn = run_once()
-        self.pool.kv = kv      # donated in, aliased out
-        self._note_fused_dispatch(cap)
-        self._last_scrub = self._drain_scrub(scrub_pending)
-        if self.policy != "off":
-            buf, n, steps, done, recompute = self._fault_escalate(
-                run_once, buf, n, steps, done, syn, np.asarray(tabs))
-        else:
-            recompute = np.zeros(tok0.shape[0], bool)
+        with tr.span("segment.dispatch"):
+            buf, n, steps, kv, done, syn = run_once()
+            self.pool.kv = kv      # donated in, aliased out
+            self._note_fused_dispatch(cap)
+        with tr.span("segment.wait"):
+            # the first read that blocks on the segment: its syndromes under
+            # a fault policy (the escalation layer's input), else n
+            self._last_scrub = self._drain_scrub(scrub_pending)
+            syn = np.asarray(syn) if self.policy != "off" else None
+            n = int(n)
+        recompute = np.zeros(B, bool)
+        if syn is not None and syn.any():
+            with tr.span("segment.escalate"):
+                buf, n, steps, done, recompute = self._fault_escalate(
+                    run_once, buf, n, steps, done, syn, np.asarray(tabs))
+                n = int(n)
         self._last_recompute = recompute
-        n = int(n)             # the single host sync of the segment
-        steps = int(steps)
+        with tr.span("segment.readback"):
+            steps = int(steps)
+            toks, done = np.asarray(buf)[:, :n], np.asarray(done)
         self.stats.decode_steps += steps
         self.stats.decode_dispatches += 1
         self._sync_fallback_gathers()
-        counts = np.full(tok0.shape[0], steps, np.int64)
-        return np.asarray(buf)[:, :n], steps, np.asarray(done), counts, 0, 0
+        counts = np.full(B, steps, np.int64)
+        return toks, steps, done, counts, 0, 0
 
     # -- fault-domain escalation (DESIGN.md §15) -----------------------------
 
@@ -871,14 +889,16 @@ class ServingEngine:
         pool.kv = kvp.PagedKV(new["k"], new["v"])
         return ledger
 
-    def _fault_escalate(self, run_once, buf, n, steps, done, syn, tabs_np):
+    def _fault_escalate(self, run_once, buf, n, steps, done, syn_np,
+                        tabs_np):
         """Escalate nonzero in-kernel syndromes: detect -> correct ->
         quarantine -> recompute.
 
-        ``syn`` is the segment's ``(B, L)`` per-(slot, layer) faulty-element
-        map.  Clean segments (the overwhelmingly common case) host-read one
-        small int32 array and return immediately — no repair pass, no
-        standalone ``verify_pages`` sweep on the hot path.
+        ``syn_np`` is the segment's ``(B, L)`` per-(slot, layer)
+        faulty-element map, read to the host; the caller escalates only
+        when it is nonzero.  Clean segments (the overwhelmingly common
+        case) cost that one small read — no repair pass, no standalone
+        ``verify_pages`` sweep on the hot path.
 
         Escalation rounds (``policy="correct"``/``"strict"``): repair the
         flagged slots' pages at the flagged layers, charge each faulty page
@@ -894,11 +914,7 @@ class ServingEngine:
         pool = self.pool
         B = tabs_np.shape[0]
         recompute = np.zeros(B, bool)
-        syn_np = np.asarray(syn)
-        total = int(syn_np.sum())
-        if total == 0:
-            return buf, n, steps, done, recompute
-        self.stats.faults.syndromes += total
+        self.stats.faults.syndromes += int(syn_np.sum())
         if self.policy == "detect":
             return buf, n, steps, done, recompute
         replays = 0
@@ -1040,8 +1056,10 @@ class ServingEngine:
         prefix-cached, from the logits cache (the prefill is skipped).
         """
         pool = self.pool
-        infos = {s: pool.admit(np.asarray(slot_tokens[s]), slot_total[s])
-                 for s in sorted(slot_tokens)}
+        tr = self.tracer
+        with tr.span("admit.pages"):
+            infos = {s: pool.admit(np.asarray(slot_tokens[s]), slot_total[s])
+                     for s in sorted(slot_tokens)}
         need = [s for s, inf in infos.items() if inf.cached_logits is None]
         out = {s: (infos[s].cached_logits, infos[s]) for s in infos
                if infos[s].cached_logits is not None}
@@ -1061,17 +1079,23 @@ class ServingEngine:
             prompts[s, : len(toks)] = toks
             logits_at[s] = len(toks) - 1
             tabs[s] = pool.tab_row(infos[s].pages, self.n_pmax)
-        logits, cache = self._prefill(
-            self.params, {"tokens": jnp.asarray(prompts)}, s_max=s_buck,
-            logits_at=jnp.asarray(logits_at))
-        logits = np.asarray(logits)
+        rows = self.batch * s_buck
+        used = sum(len(slot_tokens[s]) for s in need)
+        self.stats.prefill_rows += rows
+        self.stats.prefill_tokens += used
+        with tr.span("admit.prefill", attrs={"rows": rows, "tokens": used}):
+            logits, cache = self._prefill(
+                self.params, {"tokens": jnp.asarray(prompts)}, s_max=s_buck,
+                logits_at=jnp.asarray(logits_at))
+            logits = np.asarray(logits)
         # non-admitted rows keep all-dump tab rows, so their padding
         # garbage scatters into the dump page; prefix-shared pages are
         # rewritten with identical bytes (page contents are a pure
         # function of the token prefix)
-        pool.kv = self._scatter(pool.kv, cache.k, cache.v,
-                                jnp.asarray(tabs),
-                                page_size=self.page_size)
+        with tr.span("admit.scatter"):
+            pool.kv = self._scatter(pool.kv, cache.k, cache.v,
+                                    jnp.asarray(tabs),
+                                    page_size=self.page_size)
         for s in need:
             pool.remember_logits(slot_tokens[s], logits[s])
             out[s] = (logits[s], infos[s])
@@ -1114,7 +1138,7 @@ class ServingEngine:
         """
         greedy = temperature <= 0.0 or key is None
         buf, steps, done, counts, prop, acc = self._dispatch_segment(
-            jnp.asarray(tok0, jnp.int32), pos0, eos_vec, done0, remaining,
+            tok0, pos0, eos_vec, done0, remaining,
             tabs, seg, temperature, key, key_base, stop_on_finish, greedy)
         f_det, f_cor = self._last_scrub
         return SegmentResult(tokens=buf, steps=steps, done=done,

@@ -65,6 +65,10 @@ class _Slot:
     tab: np.ndarray               # (n_pmax,) block-table row
     pages: list[int]              # pages to release at retirement
 
+    @property
+    def rid(self) -> int:
+        return self.req.rid
+
 
 class RequestScheduler:
     def __init__(self, engine: ServingEngine, *, pad_token: int = 0):
@@ -76,20 +80,22 @@ class RequestScheduler:
         queue = list(requests)
         done: list[Request] = []
         self._t0 = time.perf_counter()
-        if self.engine.paged:
-            done = self._serve_continuous(queue)
-        else:
-            B = self.engine.batch
-            while queue:
-                round_reqs = queue[:B]
-                queue = queue[B:]
-                done += self._run_round(round_reqs)
+        with self.engine.tracer.span("serve", queue):
+            if self.engine.paged:
+                done = self._serve_continuous(queue)
+            else:
+                B = self.engine.batch
+                while queue:
+                    round_reqs = queue[:B]
+                    queue = queue[B:]
+                    done += self._run_round(round_reqs)
         return sorted(done, key=lambda r: r.rid)
 
     # -- continuous batching (paged engine) ----------------------------------
 
     def _serve_continuous(self, queue: list[Request]) -> list[Request]:
         eng = self.engine
+        tr = eng.tracer
         B = eng.batch
         cap = eng.n_pmax * eng.page_size      # per-request KV capacity
         slots: dict[int, _Slot] = {}
@@ -123,8 +129,13 @@ class RequestScheduler:
                 # unchanged by recompute re-admissions)
                 batch_total[s] = min(
                     len(r.tokens) + r.max_new + eng.spec_lookahead, cap)
-            if not pend:
-                return
+            if pend:
+                with tr.span("sched.admit", pend.values()):
+                    seat(pend, batch_toks, batch_total)
+
+        def seat(pend: dict[int, Request], batch_toks: dict[int, np.ndarray],
+                 batch_total: dict[int, int]) -> None:
+            """Prefill the popped requests and seat them in their slots."""
             admitted = eng.admit_prefill(batch_toks, batch_total)
             for s, r in pend.items():
                 logits, info = admitted[s]
@@ -157,24 +168,23 @@ class RequestScheduler:
 
         def retire(slot: _Slot) -> None:
             r = slot.req
-            toks = np.asarray(slot.emitted[: r.max_new], np.int32)
-            if r.eos is not None:
-                hits = np.nonzero(toks == r.eos)[0]
-                if hits.size:
-                    toks = toks[: hits[0] + 1]
-            r.result = toks
-            freed_before = eng.pool.stats.pages_freed
-            eng.pool.release(slot.pages)
-            r.stats.pages_freed = eng.pool.stats.pages_freed - freed_before
-            r.stats.latency_s = time.perf_counter() - self._t0
-            finished.append(r)
+            with tr.span("sched.retire", (r,)):
+                toks = np.asarray(slot.emitted[: r.max_new], np.int32)
+                if r.eos is not None:
+                    hits = np.nonzero(toks == r.eos)[0]
+                    if hits.size:
+                        toks = toks[: hits[0] + 1]
+                r.result = toks
+                freed_before = eng.pool.stats.pages_freed
+                eng.pool.release(slot.pages)
+                r.stats.pages_freed = (eng.pool.stats.pages_freed
+                                       - freed_before)
+                r.stats.latency_s = time.perf_counter() - self._t0
+                finished.append(r)
 
-        while queue or slots:
-            free = [s for s in range(B) if s not in slots]
-            if queue and free:
-                admit(free)
-            if not slots:
-                continue    # admitted requests all finished on prefill
+        def segment() -> None:
+            """One fused decode segment over the live slots, then their
+            bookkeeping: tokens appended, finished requests retired."""
             tok0 = np.zeros((B, 1), np.int32)
             pos0 = np.zeros(B, np.int32)
             remaining = np.zeros(B, np.int32)
@@ -246,6 +256,15 @@ class RequestScheduler:
                         or len(sl.emitted) >= r.max_new):
                     del slots[s]
                     retire(sl)
+
+        while queue or slots:
+            free = [s for s in range(B) if s not in slots]
+            if queue and free:
+                admit(free)
+            if not slots:
+                continue    # admitted requests all finished on prefill
+            with tr.span("sched.segment", slots.values()):
+                segment()
         return finished
 
     # -- fixed rounds (dense / baseline engines) -----------------------------

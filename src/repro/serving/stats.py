@@ -15,6 +15,9 @@ bare ``decode_steps`` / ``decode_dispatches`` / ``fused_retraces`` ints,
 * :class:`FaultStats` — the redundant-residue corruption counters (new in
   the fault-tolerance work; these land *only* on the typed surface).
 
+Timed spans of the same path live in :mod:`repro.serving.trace`
+(``engine.tracer``, off by default); counters stay here.
+
 The old attribute paths still work as ``DeprecationWarning`` property
 shims (kept green under the ``-W error::DeprecationWarning`` CI variant);
 :func:`deprecated_stat` builds them.
@@ -132,6 +135,10 @@ class EngineStats:
 
     decode_steps: int = 0        # decode tokens produced
     decode_dispatches: int = 0   # host->device decode dispatches
+    # admission prefills: batch rows x bucket as computed, and the prompt
+    # tokens of the slots that needed them (their ratio is the useful share)
+    prefill_rows: int = 0
+    prefill_tokens: int = 0
     fused_retraces: int = 0      # fused-loop retraces (new length buckets)
     # channel_shard plan resolutions that fell back to the replicated /
     # gathered decode layout (C not divisible by the tensor axis, or a

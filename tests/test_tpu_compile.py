@@ -15,13 +15,17 @@ test module.  The persistent compilation cache is off around the compiles
 """
 from __future__ import annotations
 
+import dataclasses
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.core.moduli import KV8, KV8R2
 from repro.kernels.flash_attn import (
     flash_attention_pallas,
@@ -30,7 +34,10 @@ from repro.kernels.flash_attn import (
 )
 from repro.kernels.rns_matmul import rns_matmul_pallas
 from repro.kernels.sdrns_matmul import sdrns_matmul_pallas, sdrns_matvec_pallas
+from repro.models.api import build_model
+from repro.models.attention import set_attn_impl
 from repro.numerics.runners import _choose_blocks
+from repro.serving.kv_pool import KVPagePool
 
 D_MODEL, D_FF, H, KV, HD = 4096, 11008, 32, 4, 128
 BATCH, PAGE, N_PMAX = 8, 64, 9
@@ -112,6 +119,53 @@ def test_paged_decode_compiles(one_chip, fmt, syndrome):
                  q, k, v, t, n, page_size=PAGE, k_scale=ks, v_scale=vs,
                  moduli=mset.info_moduli, red_moduli=red, interpret=False),
              one_chip, *common, planes, planes, *tab, scale, scale)
+
+
+def _bench_kernel_names() -> list[str]:
+    """``TRACE`` of each ``bench/kernels/*.py``: the instruction name the
+    benchmark's device-trace reduction finds each kernel by."""
+    bench = Path(__file__).resolve().parents[1] / "bench" / "kernels"
+    names = []
+    for f in sorted(bench.glob("*.py")):
+        m = re.search(r'^TRACE = "([^"]+)"', f.read_text(), re.M)
+        if m:
+            names.append(m.group(1))
+    return names
+
+
+def test_decode_step_keeps_kernel_names(one_chip):
+    """The main-path decode step (rns weights, rns8r pages with in-kernel
+    syndromes, sub-layer device scopes), compiled at yi-6b widths for one
+    layer, holds an instruction named after every kernel the benchmark
+    reads: scopes change op metadata, never these names."""
+    names = _bench_kernel_names()
+    assert len(names) >= 2
+    cfg = dataclasses.replace(get_config("yi-6b"), n_layers=1, vocab=4096)
+    model = build_model(cfg, system="rns", rns_bits=4, rns_impl="pallas")
+    params = jax.eval_shape(
+        lambda: model.prepare_params(model.init(jax.random.PRNGKey(0))))
+    pool = KVPagePool(1, 1 + BATCH * 3, PAGE, cfg.n_kv, cfg.hd, fmt="rns8r")
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def step(params, tok, kv, tab, pos):
+        return model.decode_paged(params, tok, kv, tab, pos, page_size=PAGE,
+                                  with_syndrome=True)
+
+    prev = set_attn_impl("pallas")
+    try:
+        text = jax.jit(step).lower(
+            jax.tree_util.tree_map(spec, params),
+            spec(jnp.zeros((BATCH, 1), jnp.int32)),
+            jax.tree_util.tree_map(spec, pool.kv),
+            spec(jnp.zeros((BATCH, 3), jnp.int32)),
+            spec(jnp.zeros((BATCH,), jnp.int32))).compile().as_text()
+    finally:
+        set_attn_impl(prev)
+    for name in names:
+        assert re.search(rf"%{re.escape(name)}(\.\d+)? = ", text), name
+    assert "/mlp.down/" in text and "/attn.core/" in text
 
 
 @pytest.mark.parametrize("kernel", [sdrns_matmul_pallas, sdrns_matvec_pallas])

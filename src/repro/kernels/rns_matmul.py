@@ -10,11 +10,17 @@ reduce-and-center runs on the last K step.  The inner loop is therefore a pure
 ``dot_general`` chain: MXU-only, no elementwise mod traffic.
 
 Tiling: grid ``(C, M/bm, N/bn, K/bk)`` with the K axis innermost/sequential
-("arbitrary" semantics on TPU).  Blocks are MXU-aligned (multiples of 128 on
-the matmul dims; bk a multiple of 128 as well).  VMEM footprint per step is
-``bm*bk + bk*bn`` (int8) ``+ bm*bn`` (int32 accumulator) — the default
-(128, 128, 512) tile uses 128KiB + 64KiB ≈ 0.2 MiB, far under the ~16 MiB/core
-VMEM budget, leaving room for double-buffered pipelining.
+("arbitrary" semantics on TPU).  The caller picks the blocks from the call's
+own shape (``numerics/runners.py::_choose_blocks``): ``bn`` and ``bk`` divide
+``N`` and the K segment wherever those are multiples of 128, so weight planes
+reach the kernel unpadded, and each grid step streams a weight block of
+1-4 MiB (whole K where it fits), because a grid step has a fixed cost that a
+64 KiB block cannot hide.  At decode (M <= 512) one M block holds every row,
+so each weight byte is read once; at prefill ``bm`` grows to 512 so each step
+does enough MXU work to hide its weight DMA.  :func:`vmem_bytes` is the
+double-buffered footprint of one step; the chooser keeps it within
+:data:`VMEM_BUDGET` and the kernel asks Mosaic for :data:`VMEM_LIMIT`, above
+the v5e's 16 MiB scoped default.
 """
 from __future__ import annotations
 
@@ -27,9 +33,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
 
-__all__ = ["rns_matmul_pallas", "DEFAULT_BLOCKS"]
+__all__ = ["rns_matmul_pallas", "DEFAULT_BLOCKS", "VMEM_BUDGET", "VMEM_LIMIT",
+           "vmem_bytes"]
 
 DEFAULT_BLOCKS = (128, 128, 512)  # (bm, bn, bk)
+VMEM_BUDGET = 24 * 1024 * 1024    # blocks of one grid step, double-buffered
+VMEM_LIMIT = 32 * 1024 * 1024     # scoped VMEM asked of Mosaic: the budget
+                                  # plus room for the compiler's own scratch
+
+
+def vmem_bytes(bm: int, bn: int, bk: int, itemsize: int = 1) -> int:
+    """VMEM one grid step holds: both residue operand blocks and the int32
+    output block double-buffered, plus the int32 product of the step."""
+    return 2 * (bm * bk + bk * bn) * itemsize + 3 * bm * bn * 4
 
 
 def _kernel(m_ref, a_ref, b_ref, out_ref, *, n_k: int):
@@ -113,7 +129,8 @@ def rns_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((C, M, N), jnp.int32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         interpret=interpret,
     )(moduli.astype(jnp.int32), a_res, b_res)

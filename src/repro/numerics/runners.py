@@ -62,7 +62,11 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import sd, sdrns
 from repro.core.moduli import ModuliSet
-from repro.kernels.rns_matmul import rns_matmul_pallas
+from repro.kernels.rns_matmul import (
+    VMEM_BUDGET,
+    rns_matmul_pallas,
+    vmem_bytes,
+)
 from repro.kernels.sd_add import sd_add_pallas
 from repro.kernels.sdrns_matmul import (
     WRAP_SIGNS,
@@ -224,12 +228,38 @@ def segment_count(K: int, max_abs_a: int, max_abs_b: int,
 # ---------------------------------------------------------------------------
 
 
-def _choose_blocks(M: int, N: int, K: int) -> tuple[int, int, int]:
-    """MXU-aligned tiles that do not over-pad small problems."""
-    bm = 128 if M >= 128 else _round_up(M, 8)
-    bn = 128 if N >= 128 else _round_up(N, 128)  # lane dim: keep 128
-    bk = 512 if K >= 512 else _round_up(K, 128)
-    return bm, max(bn, 128), max(bk, 128)
+# Largest weight block (bytes) one grid step streams: 1-4 MiB hides the
+# fixed cost of a grid step (about 0.37 us on a v5e) behind the block's DMA.
+_WEIGHT_BLOCK_BYTES = 4 * 1024 * 1024
+# Rows of one grid step once M outgrows one block (admission prefill): enough
+# MXU work per step to hide the weight block's DMA.
+_MAX_BM = 512
+
+
+def _tiles(dim: int) -> list[int]:
+    """Block sizes for one lane-aligned axis: the multiples of 128 that divide
+    ``dim`` rounded up to 128, so an aligned axis is never padded."""
+    padded = _round_up(dim, 128)
+    return [t for t in range(128, padded + 1, 128) if padded % t == 0]
+
+
+def _choose_blocks(M: int, N: int, K: int,
+                   itemsize: int = 1) -> tuple[int, int, int]:
+    """``(bm, bn, bk)`` of one ``rns_matmul`` call over a K segment of
+    ``K``, from the call's own shape.
+
+    ``bk`` and ``bn`` divide ``K`` and ``N`` (each rounded up to 128), so
+    aligned weight planes reach the kernel as they are.  Among the pairs
+    whose weight block fits :data:`_WEIGHT_BLOCK_BYTES` and whose grid step
+    fits the kernel's VMEM budget, the whole K segment wins first (one
+    reduction, no accumulator traffic), then the widest column block.  Up to
+    :data:`_MAX_BM` rows ride one M block, so each weight byte is read once.
+    """
+    bm = _round_up(M, 8) if M <= _MAX_BM else _MAX_BM
+    bk, bn = max((bk, bn) for bk in _tiles(K) for bn in _tiles(N)
+                 if bk * bn * itemsize <= _WEIGHT_BLOCK_BYTES
+                 and vmem_bytes(bm, bn, bk, itemsize) <= VMEM_BUDGET)
+    return bm, bn, bk
 
 
 register_impl(
@@ -362,31 +392,57 @@ def rns_run(a, b_res, *, mset, max_abs_a, max_abs_b, backend, shard=None,
     decode = mset.corrected_decode if (verify and mset.redundant) \
         else mset.from_residues
     M, K = a.shape
-    C, K2, N = b_res.shape
+    _, K2, N = b_res.shape
     assert K == K2, (a.shape, b_res.shape)
 
-    res_dtype = _res_dtype(mset)
-    a_res = mset.to_residues(a.astype(jnp.int32)).astype(res_dtype)
+    a_res = mset.to_residues(a.astype(jnp.int32)).astype(_res_dtype(mset))
 
+    blocks, segments = _segments(a_res, b_res, mset=mset, max_abs_a=max_abs_a,
+                                 max_abs_b=max_abs_b)
+    total = jnp.zeros((M, N), jnp.int32)
+    for a_p, b_p in segments:
+        out_res = impl(a_p, b_p, mset, *blocks)
+        total = total + decode(out_res[:, :M, :N])
+    return total
+
+
+def _pad_to(x: jax.Array, shape: tuple[int, ...]) -> jax.Array:
+    """``x`` zero-padded at the end of each axis to ``shape`` (``x`` itself
+    where it has that shape already)."""
+    if x.shape == shape:
+        return x
+    return jnp.pad(x, [(0, t - d) for d, t in zip(x.shape, shape)])
+
+
+def _segments(a_res, b_res, *, mset, max_abs_a, max_abs_b):
+    """K segments of the residue operands, shaped for the ``rns_matmul``
+    kernel: ``((bm, bn, bk), [(a_seg, b_seg), ...])``.
+
+    Segments keep each exact partial product inside the moduli range; the
+    tiles come from :func:`_choose_blocks`.  An operand is padded only where
+    its shape is not a multiple of the tiles; padding a weight operand copies
+    its planes on every call, so it is counted (``plane_pad``, trace time).
+    """
+    from repro.quant import residency
+
+    C, M, K = a_res.shape
+    N = b_res.shape[-1]
     segs = segment_count(K, max_abs_a, max_abs_b, mset)
     seg_len = _round_up((K + segs - 1) // segs, 128)
     segs = (K + seg_len - 1) // seg_len
 
-    bm, bn, bk = _choose_blocks(M, N, seg_len)
+    bm, bn, bk = _choose_blocks(M, N, seg_len, a_res.dtype.itemsize)
     Mp, Np = _round_up(M, bm), _round_up(N, bn)
     Kp = _round_up(seg_len, bk)
-
-    total = jnp.zeros((M, N), jnp.int32)
+    segments = []
     for s in range(segs):
-        lo = s * seg_len
-        hi = min(lo + seg_len, K)
-        a_s = a_res[:, :, lo:hi]
+        lo, hi = s * seg_len, min((s + 1) * seg_len, K)
         b_s = b_res[:, lo:hi, :]
-        a_p = jnp.zeros((C, Mp, Kp), res_dtype).at[:, :M, : hi - lo].set(a_s)
-        b_p = jnp.zeros((C, Kp, Np), res_dtype).at[:, : hi - lo, :N].set(b_s)
-        out_res = impl(a_p, b_p, mset, bm, bn, bk)
-        total = total + decode(out_res[:, :M, :N])
-    return total
+        if b_s.shape != (C, Kp, Np):
+            residency.record("plane_pad")
+        segments.append((_pad_to(a_res[:, :, lo:hi], (C, Mp, Kp)),
+                         _pad_to(b_s, (C, Kp, Np))))
+    return (bm, bn, bk), segments
 
 
 def _rns_channel_body(a, b_res, *, mset, max_abs_a, max_abs_b, backend,
@@ -413,32 +469,17 @@ def _rns_channel_body(a, b_res, *, mset, max_abs_a, max_abs_b, backend,
 
     cid = _channel_ids(tp, C_loc)
     moduli = jnp.take(jnp.asarray(mset.moduli, jnp.int32), cid)
-    res_dtype = _res_dtype(mset)
     # Forward conversion needs every channel's residues of the activations;
     # it is elementwise (cheap, collective-free), so convert all C and keep
     # the local slice by traced gather.
     a_all = mset.to_residues(a.astype(jnp.int32))        # (C, M, K)
-    a_res = jnp.take(a_all, cid, axis=0).astype(res_dtype)
+    a_res = jnp.take(a_all, cid, axis=0).astype(_res_dtype(mset))
 
-    segs = segment_count(K, max_abs_a, max_abs_b, mset)
-    seg_len = _round_up((K + segs - 1) // segs, 128)
-    segs = (K + seg_len - 1) // seg_len
-
-    bm, bn, bk = _choose_blocks(M, N, seg_len)
-    Mp, Np = _round_up(M, bm), _round_up(N, bn)
-    Kp = _round_up(seg_len, bk)
-
+    blocks, segments = _segments(a_res, b_res, mset=mset, max_abs_a=max_abs_a,
+                                 max_abs_b=max_abs_b)
     parts = []
-    for s in range(segs):
-        lo = s * seg_len
-        hi = min(lo + seg_len, K)
-        a_s = a_res[:, :, lo:hi]
-        b_s = b_res[:, lo:hi, :]
-        a_p = jnp.zeros((C_loc, Mp, Kp), res_dtype)
-        a_p = a_p.at[:, :M, : hi - lo].set(a_s)
-        b_p = jnp.zeros((C_loc, Kp, Np), res_dtype)
-        b_p = b_p.at[:, : hi - lo, :N].set(b_s)
-        out_res = impl(a_p, b_p, moduli, bm, bn, bk)[:, :M, :N]
+    for a_p, b_p in segments:
+        out_res = impl(a_p, b_p, moduli, *blocks)[:, :M, :N]
         rows = mset.partial_decode(out_res, cid)[None]   # (1, M, N)
         if witness:
             rows = jnp.concatenate(
@@ -447,7 +488,7 @@ def _rns_channel_body(a, b_res, *, mset, max_abs_a, max_abs_b, backend,
 
     buf = jax.lax.psum(jnp.stack(parts, axis=0), tp)     # (segs, 1+r, M, N)
     total = jnp.zeros((M, N), jnp.int32)
-    for s in range(segs):
+    for s in range(len(parts)):
         if witness:
             total = total + mset.corrected_fold(buf[s, 0], buf[s, 1:])
         else:

@@ -39,7 +39,7 @@ from repro.models.attention import set_attn_impl
 from repro.numerics.runners import _choose_blocks
 from repro.serving.kv_pool import KVPagePool
 
-D_MODEL, D_FF, H, KV, HD = 4096, 11008, 32, 4, 128
+D_MODEL, D_FF, H, KV, HD, VOCAB = 4096, 11008, 32, 4, 128, 64000
 BATCH, PAGE, N_PMAX = 8, 64, 9
 
 
@@ -69,15 +69,28 @@ def _compile(fn, sharding, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("M,K,N", [(BATCH, D_MODEL, D_MODEL),   # decode q/o
-                                   (BATCH, D_FF, D_MODEL),      # decode down
-                                   (4096, D_MODEL, D_FF)])      # prefill up
+# Every weight matmul shape of yi-6b's two serving programs: decode at batch
+# 16 (q/o, k/v, gate/up, down, the tied logits at vocab 64000) and at 8,
+# admission prefill at 16 x bucket 256, speculative verify at 16 x (4 + 1);
+# and one shape that is not lane-aligned.
+RNS_SHAPES = (
+    [(BATCH, D_MODEL, D_MODEL), (BATCH, D_FF, D_MODEL)]
+    + [(M, K, N) for M in (16, 4096, 80)
+       for K, N in ((D_MODEL, D_MODEL), (D_MODEL, KV * HD), (D_MODEL, D_FF),
+                    (D_FF, D_MODEL))]
+    + [(16, D_MODEL, VOCAB), (16, 300, 100)])
+
+
+@pytest.mark.parametrize("M,K,N", RNS_SHAPES)
 def test_rns_matmul_compiles(one_chip, M, K, N):
+    """With the tiles the runner's rule picks: aligned planes unpadded."""
     bm, bn, bk = _choose_blocks(M, N, K)
-    K = -(-K // bk) * bk                 # the runner pads K to the tile
+    Mp, Np, Kp = (-(-d // t) * t for d, t in ((M, bm), (N, bn), (K, bk)))
+    if K % 128 == 0 and N % 128 == 0:
+        assert (Kp, Np) == (K, N)
     _compile(lambda a, b, m: rns_matmul_pallas(a, b, m, bm=bm, bn=bn, bk=bk,
                                                interpret=False),
-             one_chip, ((3, M, K), jnp.int8), ((3, K, N), jnp.int8),
+             one_chip, ((3, Mp, Kp), jnp.int8), ((3, Kp, Np), jnp.int8),
              ((3,), jnp.int32))
 
 

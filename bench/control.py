@@ -65,9 +65,9 @@ def main(argv=None) -> int:
 
 def read(cell, seed: int, fault, with_control: bool) -> None:
     """One seed's readings, printed as one JSON line."""
-    from harness import check, reference as ref, serve
+    from harness import check, serve
 
-    conf = cell.config
+    conf, fam = cell.config, cell.family
     s_pad = check.pad_len(cell.traffic)
     rec = serve.Recorder()
     sv = serve.build(cell, seed, rec)
@@ -77,13 +77,14 @@ def read(cell, seed: int, fault, with_control: bool) -> None:
     dims = sv.dims
     del sv, rec
     gc.collect()
-    program = check.measure(samples, seed, dims, ref.stated(conf), s_pad)
+    program = check.measure(fam, samples, seed, dims, fam.stated(conf),
+                            s_pad)
     ok, _ = check.verdict(program, conf["check"]["limits"])
     line = {"workload": cell.name, "seed": seed, "fault": fault,
             "correct": ok, "program": program}
     if with_control:
-        control = check.measure(samples, seed, dims, ref.control(conf),
-                                s_pad, against=ref.stated(conf))
+        control = check.measure(fam, samples, seed, dims, fam.control(conf),
+                                s_pad, against=fam.stated(conf))
         line["control"] = control
         line["control_correct"] = check.verdict(
             control, conf["check"]["limits"])[0]

@@ -1,7 +1,9 @@
-"""Plain reference of a served dense decoder, written from the semantics a
-configuration file states, and importing nothing of the program.
+"""Arithmetic of the plain reference that every family shares, written from
+the semantics a configuration file states, and importing nothing of the
+program.
 
-What a configuration states, and so what this computes:
+What a configuration states, and so what a family's reference computes
+with these pieces:
 
 * the residual stream and every matmul output in ``compute_dtype``
   (bfloat16 at served sizes), RMSNorm's variance in float32, RoPE in
@@ -11,15 +13,17 @@ What a configuration states, and so what this computes:
   or on symmetric integer codes (``system`` rns, ``rns_bits`` = 4): one
   scale per token of the activation and one per output column of the
   weight, an exact integer product, and the two scales applied after;
-* attention over the prompt (the prefill) on bfloat16 keys and values, and
-  attention of every later position (a decode step) on the keys and values
-  as the KV pages store them: rounded to bfloat16, and for ``rns8r`` pages
-  then held as 8-bit codes with one scale per token and head
+* cache rows as the KV pages store them: rounded to bfloat16, and for
+  ``rns8r`` pages then held as 8-bit codes with one scale per row
   (``kv_qmax`` = 119);
 * softmax and P.V in float32, logits in float32.
 
 ``Precision`` also describes the control: the same model one step lower
 (3-bit codes for 4-bit ones, fp8 e4m3 inputs for bfloat16 ones).
+
+Weights are data: a family makes them from the seed (``root_key``), hands
+them to the program, and makes them again for the reference after the
+window.
 """
 from __future__ import annotations
 
@@ -30,10 +34,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness.weights import Dims
-
 BF16, F32 = jnp.bfloat16, jnp.float32
 HI = jax.lax.Precision.HIGHEST
+
+
+def root_key(seed: int) -> jax.Array:
+    """A PRNG key for any whole-number seed (more bits than 32 included)."""
+    seed = int(seed)
+    if seed < 0:
+        raise ValueError(f"seed must be >= 0, got {seed}")
+    key = jax.random.PRNGKey(seed & 0xFFFFFFFF)
+    return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,7 +94,7 @@ def _fp8(x, axis: int):
     return (x / s).astype(jnp.float8_e4m3fn).astype(BF16), s
 
 
-def dense(x, w, p: Precision):
+def matmul(x, w, p: Precision):
     """(..., K) @ (K, N) float32 -> (..., N) float32."""
     if p.matmul == "float":
         c = jnp.dtype(p.compute)
@@ -112,8 +123,8 @@ def rope(x, positions, theta):
 
 
 def page_values(x, qmax: int):
-    """What a page stores for ``x`` (S, kv_heads, hd): ``x`` rounded to
-    bfloat16, and with ``qmax`` set its symmetric codes per (token, head)
+    """What a page stores for ``x`` (S, groups, width): ``x`` rounded to
+    bfloat16, and with ``qmax`` set its symmetric codes per (token, group)
     times their scale."""
     x = x.astype(BF16).astype(F32)
     if not qmax:
@@ -121,59 +132,6 @@ def page_values(x, qmax: int):
     amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     s = jnp.maximum(amax, 1e-8) / qmax
     return jnp.clip(jnp.round(x / s), -qmax, qmax) * s
-
-
-def attend(q, k, v):
-    """Causal GQA attention; q (S, H, hd), k and v (S, Kv, hd)."""
-    S, H, hd = q.shape
-    kv = k.shape[1]
-    qg = q.astype(F32).reshape(S, kv, H // kv, hd)
-    s = jnp.einsum("qkgd,tkd->kgqt", qg, k.astype(F32), precision=HI)
-    s = s / jnp.sqrt(F32(hd))
-    mask = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
-    s = jnp.where(mask, s, F32(-1e30))
-    pr = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("kgqt,tkd->qkgd", pr, v.astype(F32), precision=HI)
-    return o.reshape(S, H * hd)
-
-
-@functools.partial(jax.jit, static_argnames=("d", "p"))
-def forward(w: dict, tokens, n_prompt, *, d: Dims, p: Precision):
-    """Teacher-forced pass over one request (``tokens`` = prompt and served
-    tokens, padded).  Rows before ``n_prompt`` attend as the prefill does,
-    the rest as decode steps do.  Returns the logits (S, vocab) float32 and
-    each layer's page values of K and V, (L, S, kv_heads, hd)."""
-    S = tokens.shape[0]
-    c = jnp.dtype(p.compute)
-    pos = jnp.arange(S, dtype=jnp.int32)
-    prefill_row = (pos < n_prompt)[:, None]
-    x = w["embed"].astype(c)[tokens]
-
-    def proj(h, wt, heads=None):
-        y = dense(h, wt, p).astype(c)
-        return y if heads is None else y.reshape(S, heads, d.head_dim)
-
-    def layer(x, lw):
-        hn = rmsnorm(x, lw["attn_norm"], d.norm_eps)
-        q = proj(hn, lw["wq"], d.heads)
-        k = proj(hn, lw["wk"], d.kv_heads)
-        v = proj(hn, lw["wv"], d.kv_heads)
-        q, k = rope(q, pos, d.rope_theta), rope(k, pos, d.rope_theta)
-        kp, vp = page_values(k, p.kv_qmax), page_values(v, p.kv_qmax)
-        o = jnp.where(prefill_row, attend(q, k, v), attend(q, kp, vp))
-        x = x + proj(o.astype(c), lw["wo"])
-        hn = rmsnorm(x, lw["mlp_norm"], d.norm_eps)
-        g, u = proj(hn, lw["w_gate"]), proj(hn, lw["w_up"])
-        h = jax.nn.silu(g.astype(F32)).astype(c) * u
-        x = x + proj(h, lw["w_down"])
-        return x, (kp, vp)
-
-    names = ("attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "w_gate",
-             "w_up", "w_down")
-    x, (kp, vp) = jax.lax.scan(layer, x, {n: w[n] for n in names})
-    xf = rmsnorm(x, w["final_norm"], d.norm_eps)
-    logits = dense(xf, w["embed"].T, p)
-    return logits, kp, vp
 
 
 @functools.lru_cache(maxsize=None)
@@ -198,7 +156,7 @@ def crt_table(moduli: tuple[int, int]) -> np.ndarray:
 def decode_pages(planes: np.ndarray, scale: np.ndarray,
                  moduli: tuple[int, int]) -> np.ndarray:
     """Stored values of residue pages: lane 0 of ``planes``
-    (..., lanes, kv_heads, hd) uint8 through the CRT table of the pair
-    ``moduli``, times ``scale`` (..., kv_heads, 1)."""
+    (..., lanes, groups, width) uint8 through the CRT table of the pair
+    ``moduli``, times ``scale`` (..., groups, 1)."""
     table = crt_table(tuple(moduli))
     return table[planes[..., 0, :, :]].astype(np.float32) * scale

@@ -10,36 +10,33 @@ one's prompt and served tokens, and two numbers are compared:
   percentile over all the sample's tokens, and ``tok_gap_request_median``,
   the largest of the requests' own medians, which a fault confined to the
   slot of one sampled request moves;
-* ``kv_err``: the KV rows the window wrote into the page pool, read back
-  and decoded here, against the reference's rows: the relative error of
-  each (position, KV head) row, its median over the sample's rows of one
-  layer, of K or V, and of one writer (the prefill wrote the prompt's rows,
-  decode steps the rest), and the worst of those medians.  A median,
-  because with 4-bit codes a row a rounding flip has moved stays moved;
-  per writer, so that a fault of either is not outvoted by the other's
-  rows.  Only requests whose pages no later request was given can be read
-  back.
+* ``kv_err``: the cache rows the window wrote into the page pool, read
+  back and decoded by the model's family, against the reference's rows:
+  the relative error of each (position, group) row, its median over the
+  sample's rows of one layer, of one part (K or V for a dense block), and
+  of one writer (the prefill wrote the prompt's rows, decode steps the
+  rest), and the worst of those medians.  A median, because with 4-bit
+  codes a row a rounding flip has moved stays moved; per writer, so that a
+  fault of either is not outvoted by the other's rows.  Only requests whose
+  pages no later request was given can be read back.
 
 Each number has a limit of its own, in the configuration file under
-``check``.
+``check``.  What is model-specific (weights, the reference's forward pass,
+reading rows back) the configuration's family does (``harness.arch``).
 """
 from __future__ import annotations
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-
-from harness import reference as ref
-from harness.weights import Dims, make_weights
 
 
 @dataclasses.dataclass
 class Sample:
     prompt: np.ndarray
     tokens: np.ndarray
-    pages: np.ndarray | None     # (L, 2, positions, kv_heads, hd) or None
+    rows: np.ndarray | None      # (L, parts, positions, groups, width)
 
 
 def choose(done, n: int, seed: int, intact) -> list[int]:
@@ -55,40 +52,21 @@ def choose(done, n: int, seed: int, intact) -> list[int]:
 
 
 def samples(sv, done, traffic: dict, seed: int) -> list[Sample]:
-    """The sample of a window's finished requests ``done``, with the pages
-    of those that are intact read back from ``sv``'s engine."""
+    """The sample of a window's finished requests ``done``, with the cache
+    rows of those that are intact read back from ``sv``'s engine."""
     from harness.serve import intact
 
     out = []
     for i in choose(done, int(traffic["check_requests"]), seed,
                     lambda d: intact(sv, d)):
         d = done[i]
-        pages = None
+        rows = None
         if intact(sv, d):
-            pages = read_pages(sv.engine, sv.pages[id(d.prompt)],
-                               len(d.prompt) + len(d.tokens) - 1,
-                               sv.config)
-        out.append(Sample(d.prompt, d.tokens, pages))
+            rows = sv.family.served_rows(sv.engine, sv.pages[id(d.prompt)],
+                                         len(d.prompt) + len(d.tokens) - 1,
+                                         sv.config)
+        out.append(Sample(d.prompt, d.tokens, rows))
     return out
-
-
-def read_pages(engine, pages: list[int], n_pos: int, config: dict):
-    """The first ``n_pos`` KV rows of one request, every layer, as the
-    pool holds them: (L, 2, n_pos, kv_heads, hd) float32 on the host."""
-    ps = engine.page_size
-    ids = jnp.asarray(pages[: -(-n_pos // ps)], jnp.int32)
-    out = []
-    for t in (engine.pool.kv.k, engine.pool.kv.v):
-        if config["kv_format"] == "bf16":
-            vals = np.asarray(jnp.take(t, ids, axis=1).astype(jnp.float32))
-        else:
-            planes = np.asarray(jnp.take(t.planes, ids, axis=1))
-            scale = np.asarray(jnp.take(t.scale, ids, axis=1))
-            vals = ref.decode_pages(planes, scale,
-                                    tuple(config["kv_moduli"][:2]))
-        L, n_pages = vals.shape[:2]
-        out.append(vals.reshape(L, n_pages * ps, *vals.shape[3:])[:, :n_pos])
-    return np.stack(out, axis=1)
 
 
 def pad_len(traffic: dict) -> int:
@@ -98,51 +76,50 @@ def pad_len(traffic: dict) -> int:
 
 
 def row_errors(got: np.ndarray, want: np.ndarray) -> np.ndarray:
-    """Relative error of each (…, hd) row."""
+    """Relative error of each (…, width) row."""
     num = np.linalg.norm(got - want, axis=-1)
     den = np.maximum(np.linalg.norm(want, axis=-1), 1e-12)
     return num / den
 
 
-def measure(samples: list[Sample], seed: int, d: Dims, p: ref.Precision,
-            s_pad: int, against: ref.Precision | None = None) -> dict:
-    """Numbers of the sample under precision ``p``.
+def measure(family, samples: list[Sample], seed: int, d, p, s_pad: int,
+            against=None) -> dict:
+    """Numbers of the sample under ``family``'s reference at precision
+    ``p`` (``family.stated`` or ``family.control``), with shapes ``d``.
 
     With ``against`` unset, ``p`` is the reference and the sample's served
-    tokens and pages are what is judged.  With ``against`` set (the
+    tokens and cache rows are what is judged.  With ``against`` set (the
     control), ``p`` takes the program's place: its own greedy tokens and
-    page rows at the same positions are judged against the reference
+    cache rows at the same positions are judged against the reference
     computed at ``against``.
     """
-    w = make_weights(seed, d)
+    w = family.make_weights(seed, d)
     gaps = []
-    errs = {"prefill": [], "decode": []}   # (L, 2, rows) per request
+    errs = {"prefill": [], "decode": []}   # (L, parts, rows) per request
     for s in samples:
         seq = np.concatenate([s.prompt, s.tokens]).astype(np.int32)
         n = len(seq)
         toks = np.zeros(s_pad, np.int32)
         toks[:n] = seq
         P = len(s.prompt)
-        logits, kp, vp = ref.forward(w, jnp.asarray(toks), jnp.int32(P),
-                                     d=d, p=against or p)
+        logits, ref_rows = family.forward(w, jnp.asarray(toks),
+                                          jnp.int32(P), d=d, p=against or p)
         rows = slice(P - 1, n - 1)          # positions that chose a token
         if against is None:
             chosen = jnp.asarray(seq[1:n])[P - 1:]
-            got_pages = s.pages
+            got = s.rows
         else:
-            c_logits, ckp, cvp = ref.forward(w, jnp.asarray(toks),
-                                             jnp.int32(P), d=d, p=p)
+            c_logits, c_rows = family.forward(w, jnp.asarray(toks),
+                                              jnp.int32(P), d=d, p=p)
             chosen = jnp.argmax(c_logits[rows], axis=-1)
-            got_pages = np.stack([np.asarray(ckp[:, : n - 1]),
-                                  np.asarray(cvp[:, : n - 1])], axis=1)
+            got = np.asarray(c_rows[:, :, : n - 1])
         lg = logits[rows]
         gap = jnp.max(lg, axis=-1) - jnp.take_along_axis(
             lg, chosen[:, None], axis=-1)[:, 0]
         gaps.append(np.asarray(gap))
-        if got_pages is not None:
-            want = np.stack([np.asarray(kp[:, : n - 1]),
-                             np.asarray(vp[:, : n - 1])], axis=1)
-            e = row_errors(got_pages, want)          # (L, 2, pos, kv)
+        if got is not None:
+            want = np.asarray(ref_rows[:, :, : n - 1])
+            e = row_errors(got, want)        # (L, parts, pos, groups)
             for phase, rows_ in (("prefill", slice(0, P)),
                                  ("decode", slice(P, None))):
                 part = e[:, :, rows_]
@@ -157,7 +134,7 @@ def measure(samples: list[Sample], seed: int, d: Dims, p: ref.Precision,
         rows_ = np.concatenate(parts, axis=-1) if parts else None
         if rows_ is None or not rows_.shape[-1]:
             continue
-        per = np.median(rows_, axis=-1)                  # (L, 2)
+        per = np.median(rows_, axis=-1)                  # (L, parts)
         worst.append(float(per.max()))
         worst0.append(float(per[0].max()))
         worst1.extend(float(x) for x in per[1:2].max(axis=1))

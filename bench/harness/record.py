@@ -1,27 +1,34 @@
 """What a run recorded, in the form the per-layer readers and the kernel
 cost functions take: the host spans of the traced interval, the device
-trace's reduction, and the cell's shapes."""
+trace's reduction, and the cell's shapes; what is model-specific in a
+count the configuration's family answers (``Run.family``)."""
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 
+from harness import arch
 from harness.cell import BENCH
 
 
 @dataclasses.dataclass
 class Run:
     config: dict
-    dims: object
+    dims: object             # the family's Dims
     batch: int
     chips: int
     page_size: int
-    page_bytes: float        # the pool's bytes per page of K and V, a layer
+    page_bytes: float        # the pool's bytes per page of one layer's rows
     t0: float                # traced interval, host clock
     t1: float
     all_spans: list
     trace: object            # harness.trace.DeviceTrace or None
     peaks: dict
+
+    @property
+    def family(self):
+        """The configuration's model family (``harness.arch``)."""
+        return arch.family_of(self.config)
 
     @property
     def window_s(self) -> float:

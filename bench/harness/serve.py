@@ -18,16 +18,6 @@ import jax
 import numpy as np
 
 from harness import traffic as tr
-from harness.weights import Dims, dims_of, make_weights, program_params
-
-# Width keys of a configuration file and the program's ArchConfig field
-# that must hold the same number.
-_WIDTHS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-           "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv",
-           "head_dim": "hd", "vocab_size": "vocab",
-           "num_hidden_layers": "n_layers",
-           "tie_word_embeddings": "tie_embeddings",
-           "compute_dtype": "compute_dtype"}
 
 
 @dataclasses.dataclass
@@ -57,30 +47,12 @@ class Recorder:
         self.spans.append(Span(name, t0, time.perf_counter(), attrs))
 
 
-def program_config(config: dict):
-    """The program's ArchConfig for a configuration file, with its depth
-    and RoPE base; raises ``ValueError`` where the file and the program
-    disagree."""
-    from repro.configs import get_config
-
-    cfg = dataclasses.replace(get_config(config["arch"]),
-                              n_layers=int(config["num_hidden_layers"]),
-                              rope_theta=float(config["rope_theta"]))
-    bad = {k: (config[k], getattr(cfg, f)) for k, f in _WIDTHS.items()
-           if config[k] != getattr(cfg, f)}
-    if cfg.family != "dense" or cfg.mlp_type != "swiglu" or cfg.qk_norm:
-        bad["block"] = (f"dense swiglu, no qk-norm",
-                        (cfg.family, cfg.mlp_type, cfg.qk_norm))
-    if bad:
-        raise ValueError(f"configuration file and program disagree: {bad}")
-    return cfg
-
-
 @dataclasses.dataclass
 class Served:
     engine: object
     config: dict
-    dims: Dims
+    family: object             # the configuration's harness.arch module
+    dims: object               # family.Dims
     spec: tr.Spec
     rec: Recorder
     first_token: dict          # id(prompt array) -> end of its admission
@@ -93,18 +65,18 @@ def build(cell, seed: int, rec: Recorder) -> Served:
     from repro.models.api import build_model
     from repro.serving.engine import ServingEngine
 
-    conf = cell.config
-    cfg = program_config(conf)
-    dims = dims_of(conf)
+    conf, fam = cell.config, cell.family
+    cfg = fam.program_config(conf)
+    dims = fam.dims_of(conf)
     spec = tr.spec_of(cell.traffic)
     model = build_model(cfg, system=conf["system"],
                         rns_bits=int(conf.get("rns_bits", 4)))
-    params = program_params(make_weights(seed, dims))
+    params = fam.program_params(fam.make_weights(seed, dims))
     want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     got = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), params)
     if jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), want) != got:
-        raise ValueError("the program's parameter layout is not the one "
-                         "harness.weights.program_params builds")
+        raise ValueError(f"the program's parameter layout is not the one "
+                         f"{fam.__name__}.program_params builds")
     engine = ServingEngine(model, params, batch=spec.batch,
                            s_max=tr.s_max(spec),
                            page_size=int(conf["page_size"]),
@@ -112,7 +84,7 @@ def build(cell, seed: int, rec: Recorder) -> Served:
                            policy=conf["policy"], paged=True)
     del params
     jax.block_until_ready(engine.params)
-    served = Served(engine, conf, dims, spec, rec, {}, {}, {})
+    served = Served(engine, conf, fam, dims, spec, rec, {}, {}, {})
     _instrument(served)
     return served
 

@@ -1,22 +1,25 @@
 """Operations and bytes of the Pallas kernel ``flash_paged_decode``: one
 decode step of one layer over the page pool.
 
-Per live slot of length n (its position + 1): 4*H*hd*n operations (QK and
-PV) and the pages its length covers, ceil(n / page_size), at the pool's own
-bytes per page of K and V (codes, witness lanes and scales included),
-plus its query and output rows.
+Per live slot of length n (its position + 1): the family's operations per
+cached position times n (``paged_decode``, ``harness.arch``; 4*H*hd for a
+dense block: QK and PV), and the pages its length covers,
+ceil(n / page_size), at the pool's own bytes per page of one layer's rows
+(codes, witness lanes and scales included), plus its query and output
+rows.
 """
 TRACE = "flash_paged_decode_pallas"      # the kernel's op name in the device trace
 PEAK = "bf16_flops"
 
 
 def cost(run):
-    d, ps = run.dims, run.page_size
+    per_position, row_bytes, layers = run.family.paged_decode(run)
+    ps = run.page_size
     ops = byts = 0.0
     for pos0, remaining, steps in run.segments():
         for p, r in zip(pos0, remaining):
             for i in range(min(steps, r)):
                 n = p + i + 1
-                ops += 4.0 * d.heads * d.head_dim * n
-                byts += -(-n // ps) * run.page_bytes + d.q_width * 6
-    return ops * d.layers, byts * d.layers
+                ops += per_position * n
+                byts += -(-n // ps) * run.page_bytes + row_bytes
+    return ops * layers, byts * layers

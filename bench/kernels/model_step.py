@@ -1,24 +1,18 @@
-"""Model FLOPs of the work a window completed, from the configuration's
-shapes alone, the same for every number system: per token 2 x the weights
-that take part in a matmul, plus 4*H*hd*context for its attention; the
-logits matmul once per prompt (its last position) and once per generated
-token.  Padding and recomputation are not counted."""
-from harness.weights import matmul_params
-
+"""Model FLOPs of the work a window completed, the same for every number
+system, as the configuration's family counts them (``prompt_flops`` and
+``token_flops``, ``harness.arch``): each prompt once, and each generated
+token with the positions its step attends over.  Padding and recomputation
+are not counted."""
 PEAK = "bf16_flops"
 
 
 def useful_flops(run):
-    d = run.dims
-    layer_w = matmul_params(d) - d.vocab * d.d_model
-    att = 4.0 * d.layers * d.heads * d.head_dim
+    fam = run.family
     flops = 0.0
     for n in run.prompt_lengths():
-        flops += 2.0 * layer_w * n + att * n * (n + 1) / 2
-        flops += 2.0 * d.vocab * d.d_model
+        flops += fam.prompt_flops(run, n)
     for pos0, remaining, steps in run.segments():
         for p, r in zip(pos0, remaining):
-            k = min(steps, r)
-            flops += (2.0 * matmul_params(d)) * k
-            flops += att * (k * (p + 1) + k * (k - 1) / 2)
+            for i in range(min(steps, r)):
+                flops += fam.token_flops(run, p + i + 1)
     return flops

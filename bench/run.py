@@ -151,10 +151,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     dims = sv.dims
     del sv, rec
     gc.collect()
-    from harness import reference as ref
 
     t_ref = time.perf_counter()
-    numbers = check.measure(samples, seed, dims, ref.stated(conf),
+    numbers = check.measure(cell.family, samples, seed, dims,
+                            cell.family.stated(conf),
                             check.pad_len(cell.traffic))
     ok, rows = check.verdict(numbers, conf["check"]["limits"])
     result["correct"] = ok

@@ -1,8 +1,8 @@
 """A Run built by hand: spans and shapes with known counts."""
+from harness.arch.dense import Dims
 from harness.record import Run
 from harness.serve import Span
 from harness.trace import DeviceTrace
-from harness.weights import Dims
 
 DIMS = Dims(layers=2, d_model=256, d_ff=512, heads=4, kv_heads=2,
             head_dim=64, vocab=1000, rope_theta=1e4, norm_eps=1e-5)

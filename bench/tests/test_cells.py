@@ -8,71 +8,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import run as bench_run
-from harness import check, faults, reference as ref, serve, traffic as tr
-from harness.cell import CHECKOUT, load_cell
-from harness.weights import dims_of
+import tiny
+from harness import check, faults, serve, traffic as tr
+from harness.arch import reference as ref
+from harness.cell import load_cell
+from tiny import run_cell
 
-PEAKS = {"bf16_flops": 1e12, "int8_ops": 1e12, "hbm_bytes_per_s": 1e11}
-TINY = {"hidden_size": 64,
-        "intermediate_size": 96, "num_attention_heads": 4,
-        "num_key_value_heads": 4, "head_dim": 16, "num_hidden_layers": 2,
-        "vocab_size": 512, "compute_dtype": "float32", "page_size": 8}
-# at this size the program and the reference agree to rounding, so each
-# configuration's own numbers are held to tight limits here
-TINY_LIMITS = {"tok_gap": 0.05, "tok_gap_median": 0.05, "tok_gap_p90": 0.05,
-               "tok_gap_request_median": 0.05, "kv_err": 1e-3,
-               "kv_err_layer0": 1e-3, "kv_err_layer1": 1e-3}
-MIX = {"batch": 4, "wave": 8,
-       "prompt": {"min": 4, "max": 24, "dist": "log-uniform"},
-       "output": {"min": 4, "max": 12, "dist": "log-uniform"},
-       "check_requests": 4}
-
-
-@pytest.fixture(autouse=True)
-def tiny_arch(monkeypatch):
-    """The program's own tiny preset of each arch in place of its served
-    widths."""
-    import repro.configs
-
-    get = repro.configs.get_config
-    monkeypatch.setattr(repro.configs, "get_config",
-                        lambda name: get(name).reduced())
+pytestmark = pytest.mark.usefixtures("tiny_arch")
 
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
     """A checkout-like directory whose cells exist nowhere else."""
-    root = tmp_path_factory.mktemp("cells")
-    (root / "bench" / "configs").mkdir(parents=True)
-    (root / "bench" / "traffic").mkdir(parents=True)
-    spec = json.loads((CHECKOUT / "BENCHMARK.json").read_text())
-    configs, cells = [], []
-    for system in ("rns", "bns"):
-        base = json.loads(
-            (CHECKOUT / f"bench/configs/yi6b-{system}-l8.json").read_text())
-        name = f"tiny-{system}"
-        limits = {k: TINY_LIMITS[k] for k in base["check"]["limits"]}
-        (root / f"bench/configs/{name}.json").write_text(
-            json.dumps(dict(base, **TINY, check={"limits": limits})))
-        configs.append({"name": name, "source": "test",
-                        "file": f"bench/configs/{name}.json",
-                        "reduced": [], "why": "test"})
-        cells.append({"name": f"{name}.mini", "config": name,
-                      "traffic": "mini", "chips": 1, "why": "test"})
-    (root / "bench/traffic/mini.json").write_text(json.dumps(MIX))
-    e2e = [dict(m, workloads=[c["name"] for c in cells])
-           if "workloads" in m else m for m in spec["end_to_end"]]
-    (root / "BENCHMARK.json").write_text(json.dumps(
-        {**spec, "configs": configs, "workloads": cells, "end_to_end": e2e,
-         "per_layer": []}))
-    return root
-
-
-def run_cell(root, name, seed=2**31 + 11):
-    cell = load_cell(name, root=root)
-    return bench_run.run_cell(cell, seed, 0.5, False, jax.devices()[:1],
-                              PEAKS)
+    return tiny.checkout(tmp_path_factory.mktemp("cells"),
+                         {f"tiny-{s}": tiny.config(s) for s in ("rns", "bns")})
 
 
 @pytest.mark.parametrize("system", ["rns", "bns"])
@@ -131,15 +80,15 @@ def test_attention_kernel_faults_are_not_correct(root, system, fault):
 @pytest.mark.parametrize("system", ["rns", "bns"])
 def test_the_control_is_not_correct(root, system):
     cell = load_cell(f"tiny-{system}.mini", root=root)
-    conf, seed = cell.config, 5
-    spec, d = tr.spec_of(cell.traffic), dims_of(conf)
+    conf, fam, seed = cell.config, cell.family, 5
+    spec, d = tr.spec_of(cell.traffic), fam.dims_of(conf)
     rng = np.random.default_rng(0)
     samples = [check.Sample(p, rng.integers(1, d.vocab, m).astype(np.int32),
                             None)
                for p, m in tr.make_wave(spec, d.vocab, seed, 0)[:4]]
-    numbers = check.measure(samples, seed, d, ref.control(conf),
+    numbers = check.measure(fam, samples, seed, d, fam.control(conf),
                             check.pad_len(cell.traffic),
-                            against=ref.stated(conf))
+                            against=fam.stated(conf))
     ok, rows = check.verdict(numbers, conf["check"]["limits"])
     assert not ok, rows
 
